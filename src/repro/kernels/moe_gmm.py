@@ -40,17 +40,28 @@ def _pack(x: jax.Array, group_sizes: jax.Array, block_rows: int
     raw_off = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                jnp.cumsum(group_sizes)[:-1].astype(jnp.int32)])
 
+    ends = jnp.cumsum(group_sizes)
     rows = jnp.arange(t, dtype=jnp.int32)
-    expert_of = jnp.searchsorted(jnp.cumsum(group_sizes), rows, side="right"
-                                 ).astype(jnp.int32)
+    expert_of = jnp.searchsorted(ends, rows, side="right",
+                                 method="compare_all").astype(jnp.int32)
     row_map = pad_off[expert_of] + (rows - raw_off[expert_of])
 
-    x_packed = jnp.zeros((tp, d), x.dtype).at[row_map].set(x)
     nblocks = tp // block_rows
     block_start = jnp.arange(nblocks, dtype=jnp.int32) * block_rows
     block_expert = jnp.searchsorted(
-        jnp.cumsum(padded), block_start, side="right").astype(jnp.int32)
+        jnp.cumsum(padded), block_start, side="right",
+        method="compare_all").astype(jnp.int32)
     block_expert = jnp.minimum(block_expert, e - 1)
+
+    # packed row -> source row, as a gather: a scatter of (T, d) rows
+    # takes the TPU compiler many seconds at MoE widths, a gather does not
+    prow = jnp.arange(tp, dtype=jnp.int32)
+    pe = jnp.repeat(block_expert, block_rows, total_repeat_length=tp)
+    offset = prow - pad_off[pe]
+    valid = offset < group_sizes[pe]
+    src = jnp.clip(raw_off[pe] + offset, 0, t - 1)
+    x_packed = jnp.where(valid[:, None], jnp.take(x, src, axis=0),
+                         jnp.zeros((), x.dtype))
     return x_packed, block_expert, row_map
 
 

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On non-TPU backends (this CPU container) the kernels execute in
-``interpret=True`` mode — the kernel body runs as plain JAX ops, which
-validates correctness; TPU compiles the real Mosaic kernels.
+On the CPU backend the kernels execute in ``interpret=True`` mode — the
+kernel body runs as plain JAX ops, which validates correctness. Every
+other backend compiles the real kernels, so a device that cannot run
+them fails loudly instead of silently interpreting.
 """
 from __future__ import annotations
 
@@ -17,7 +18,7 @@ from . import ssd_scan as _ssd
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

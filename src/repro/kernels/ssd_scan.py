@@ -25,22 +25,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _intra_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, st_ref, seg_ref,
-                  *, chunk: int):
+def _intra_kernel(x_ref, cr_ref, cc_ref, w_ref, b_ref, c_ref, y_ref,
+                  st_ref, *, chunk: int):
     x = x_ref[0, 0, 0].astype(jnp.float32)      # (Q, P)
-    la = la_ref[0, 0, 0].astype(jnp.float32)    # (Q,)
+    cum_row = cr_ref[0, 0, 0]                   # (1, Q) inclusive cumsum
+    cum_col = cc_ref[0, 0, 0]                   # (Q, 1) the same, as a column
+    w = w_ref[0, 0, 0]                          # (Q, 1) exp(total - cum)
     bm = b_ref[0, 0, 0].astype(jnp.float32)     # (Q, N)
     cm = c_ref[0, 0, 0].astype(jnp.float32)     # (Q, N)
 
-    cum = jnp.cumsum(la)                     # (Q,) inclusive
-    total = cum[-1]
-
     # intra-chunk decay-masked scores
-    li = cum[:, None]
-    lj = cum[None, :]
     mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    delta = jnp.where(mask, li - lj, 0.0)   # mask BEFORE exp (overflow)
+    delta = jnp.where(mask, cum_col - cum_row, 0.0)  # mask BEFORE exp
     decay = jnp.where(mask, jnp.exp(delta), 0.0)
     scores = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())),
@@ -50,19 +47,16 @@ def _intra_kernel(x_ref, la_ref, b_ref, c_ref, y_ref, st_ref, seg_ref,
         preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
     # chunk state: Σ_j exp(total - cum_j) x_j ⊗ B_j   → (P, N)
-    w = jnp.exp(total - cum)                               # (Q,)
-    xw = x * w[:, None]                                    # (Q, P)
+    xw = x * w                                             # (Q, P)
     st_ref[0, 0, 0] = jax.lax.dot_general(
         xw, bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(st_ref.dtype)
-    seg_ref[0, 0, 0] = jnp.exp(total)[None]
 
 
-def _inter_kernel(c_ref, prev_ref, la_ref, yin_ref, y_ref):
+def _inter_kernel(c_ref, prev_ref, cc_ref, yin_ref, y_ref):
     cm = c_ref[0, 0, 0].astype(jnp.float32)      # (Q, N)
     prev = prev_ref[0, 0, 0].astype(jnp.float32) # (P, N)
-    la = la_ref[0, 0, 0].astype(jnp.float32)     # (Q,)
-    dec = jnp.exp(jnp.cumsum(la))[:, None]    # decay from chunk start
+    dec = jnp.exp(cc_ref[0, 0, 0])               # (Q, 1) decay from chunk start
     y_inter = jax.lax.dot_general(
         cm, prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * dec          # (Q, P)
@@ -94,6 +88,13 @@ def ssd_scan(
     # layout: (B, H, C, Q, ·) so the grid walks contiguous VMEM blocks
     xr = x.transpose(0, 2, 1, 3).reshape(bsz, h, c, q, p)
     lar = log_a.transpose(0, 2, 1).reshape(bsz, h, c, q)
+    # within-chunk cumulative log-decay, once in XLA; the kernels take it
+    # as a row and as a column, which the TPU tiling needs as 2-D blocks
+    cum = jnp.cumsum(lar.astype(jnp.float32), axis=-1)    # (B,H,C,Q)
+    cum_row, cum_col = cum[..., None, :], cum[..., None]
+    total = cum[..., -1:]                                  # (B,H,C,1)
+    w_col = jnp.exp(total - cum)[..., None]                # decay to chunk end
+    seg = jnp.exp(total[..., 0])                           # (B,H,C)
     bh = jnp.repeat(b_mat, rep, axis=2)
     ch = jnp.repeat(c_mat, rep, axis=2)
     bhr = bh.transpose(0, 2, 1, 3).reshape(bsz, h, c, q, n)
@@ -103,19 +104,18 @@ def ssd_scan(
     bspec = lambda *blk: pl.BlockSpec(  # noqa: E731
         (1, 1, 1) + blk, lambda bb, hh, cc: (bb, hh, cc) + (0,) * len(blk))
 
-    y_intra, states, seg = pl.pallas_call(
+    y_intra, states = pl.pallas_call(
         functools.partial(_intra_kernel, chunk=q),
         grid=grid,
-        in_specs=[bspec(q, p), bspec(q), bspec(q, n), bspec(q, n)],
-        out_specs=[bspec(q, p), bspec(p, n), bspec(1)],
+        in_specs=[bspec(q, p), bspec(1, q), bspec(q, 1), bspec(q, 1),
+                  bspec(q, n), bspec(q, n)],
+        out_specs=[bspec(q, p), bspec(p, n)],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, h, c, q, p), jnp.float32),
             jax.ShapeDtypeStruct((bsz, h, c, p, n), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, h, c, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(xr, lar, bhr, chr_)
-    seg = seg[..., 0]                                      # (B,H,C)
+    )(xr, cum_row, cum_col, w_col, bhr, chr_)
 
     # ---- host: inter-chunk associative scan (tiny) --------------------
     def combine(left, right):
@@ -138,11 +138,11 @@ def ssd_scan(
     y = pl.pallas_call(
         _inter_kernel,
         grid=grid,
-        in_specs=[bspec(q, n), bspec(p, n), bspec(q), bspec(q, p)],
+        in_specs=[bspec(q, n), bspec(p, n), bspec(q, 1), bspec(q, p)],
         out_specs=bspec(q, p),
         out_shape=jax.ShapeDtypeStruct((bsz, h, c, q, p), x.dtype),
         interpret=interpret,
-    )(chr_, prev, lar, y_intra)
+    )(chr_, prev, cum_col, y_intra)
 
     y = y.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)      # (B,S,H,P)
     return y, final.astype(x.dtype)

@@ -7,7 +7,8 @@ For each cell this produces, WITHOUT allocating any real tensors:
   * the collective schedule parsed from the post-SPMD HLO text
     (all-gather / all-reduce / reduce-scatter / all-to-all /
     collective-permute operand bytes),
-  * three-term roofline (compute / memory / collective seconds).
+  * three-term roofline (compute / memory / collective seconds) against
+    the published peaks of ``MODELED_KIND`` (``repro.launch.mesh.PEAKS``).
 
 Results are written one JSON per cell under experiments/dryrun/.
 """
@@ -30,10 +31,9 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import all_archs, get  # noqa: E402
-from repro.jax_compat import set_mesh  # noqa: E402
 from repro.distributed import sharding as shd  # noqa: E402
 from repro.launch.mesh import (  # noqa: E402
-    HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh, mesh_chips,
+    auto_mesh, make_production_mesh, mesh_chips, peaks,
 )
 from repro.models.config import SHAPES, cell_applicable  # noqa: E402
 from repro.models.model import cache_specs, input_specs  # noqa: E402
@@ -57,6 +57,9 @@ _ALGO_FACTOR = {
     "all-gather": 1.0, "all-reduce": 2.0, "reduce-scatter": 1.0,
     "all-to-all": 1.0, "collective-permute": 1.0,
 }
+
+#: the device kind whose peaks the roofline uses (TPU v5e)
+MODELED_KIND = "TPU v5 lite"
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 
@@ -95,11 +98,12 @@ def parse_collectives(hlo_text: str) -> dict[str, dict[str, float]]:
 
 def roofline(flops: float, hbm_bytes: float,
              coll: dict[str, dict[str, float]]) -> dict[str, float]:
-    """Three-term per-device roofline (seconds)."""
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = hbm_bytes / HBM_BW
+    """Three-term per-device roofline (seconds) on a MODELED_KIND chip."""
+    pk = peaks(MODELED_KIND)
+    compute_s = flops / pk["flops_bf16"]
+    memory_s = hbm_bytes / pk["hbm_bw"]
     coll_bytes = sum(v["bytes"] * _ALGO_FACTOR[k] for k, v in coll.items())
-    collective_s = coll_bytes / ICI_BW
+    collective_s = coll_bytes / pk["ici_bw_per_link"]
     dominant = max(
         ("compute", compute_s), ("memory", memory_s),
         ("collective", collective_s), key=lambda kv: kv[1])[0]
@@ -150,7 +154,7 @@ def lower_train_cell(cfg, shape, mesh, n_micro: int = 1
         in_shardings=(state_sh, batch_sh),
         out_shardings=(state_sh, metrics_sh),
         donate_argnums=(0,))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(state, batch)
         compiled = lowered.compile()
     return lowered, compiled
@@ -181,7 +185,7 @@ def lower_prefill_cell(cfg, shape, mesh):
         mesh, shd.fit_spec(P(dp, None, "model"), out_abs.shape, mesh))
     jitted = jax.jit(prefill, in_shardings=(params_sh, batch_sh),
                      out_shardings=out_sh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params, batch)
         compiled = lowered.compile()
     return lowered, compiled
@@ -210,7 +214,7 @@ def lower_decode_cell(cfg, shape, mesh):
         in_shardings=(params_sh, cache_sh, token_sh),
         out_shardings=(logits_sh, cache_sh),
         donate_argnums=(1,))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params, cache, token)
         compiled = lowered.compile()
     return lowered, compiled
@@ -300,7 +304,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         mesh_name = "2x16x16" if multi_pod else "16x16"
     record: dict = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name,
-        "applicable": ok,
+        "device_kind": MODELED_KIND, "applicable": ok,
     }
     if not ok:
         record["skip_reason"] = reason
@@ -309,7 +313,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     if mesh_shape is not None:
         axes = (("pod", "data", "model") if len(mesh_shape) == 3
                 else ("data", "model"))
-        mesh = jax.make_mesh(mesh_shape, axes)
+        mesh = auto_mesh(mesh_shape, axes)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh_chips(mesh)

@@ -1,19 +1,49 @@
 """Serving driver: batched continuous decoding over a slot pool.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --smoke \\
-        --requests 8 --slots 4 --max-new 16
+    PYTHONPATH=src python -m repro.launch.serve --arch h2o-danube-1.8b \\
+        --smoke --requests 8 --slots 4 --max-new 16
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Callable
 
 import jax
 import numpy as np
 
 from repro.configs import get, get_smoke
+from repro.launch.mesh import enable_compile_cache
 from repro.models import Model
+from repro.models.config import ArchConfig
 from repro.serve.engine import Request, ServeEngine
+
+
+def make_requests(cfg: ArchConfig, n: int, *, prompt_len: tuple[int, int],
+                  max_new: int, seed: int = 0) -> list[Request]:
+    """``n`` requests of random tokens, prompt lengths uniform in
+    ``[prompt_len[0], prompt_len[1])``."""
+    rng = np.random.default_rng(seed)
+    return [Request(rid=rid,
+                    prompt=rng.integers(0, cfg.vocab_size,
+                                        rng.integers(*prompt_len)).tolist(),
+                    max_new=max_new)
+            for rid in range(n)]
+
+
+def serve(cfg: ArchConfig, params: Any, requests: list[Request], *,
+          slots: int, max_len: int,
+          on_tick: Callable[[ServeEngine], None] | None = None
+          ) -> list[Request]:
+    """Answer ``requests`` on a ``ServeEngine``; returns them finished.
+
+    ``on_tick(engine)`` runs after every engine tick."""
+    if not cfg.has_decode():
+        raise ValueError(f"{cfg.name} is encoder-only; nothing to decode")
+    engine = ServeEngine(cfg, params, slots=slots, max_len=max_len)
+    for req in requests:
+        engine.submit(req)
+    return engine.run(on_tick)
 
 
 def main() -> None:
@@ -27,20 +57,17 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
     if not cfg.has_decode():
         raise SystemExit(f"{cfg.name} is encoder-only; nothing to decode")
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    engine = ServeEngine(cfg, params, slots=args.slots, max_len=args.max_len)
-
-    rng = np.random.default_rng(args.seed)
-    for rid in range(args.requests):
-        prompt = rng.integers(0, cfg.vocab_size, rng.integers(2, 6)).tolist()
-        engine.submit(Request(rid=rid, prompt=prompt, max_new=args.max_new))
+    params = jax.jit(Model(cfg).init)(jax.random.PRNGKey(args.seed))
+    requests = make_requests(cfg, args.requests, prompt_len=(2, 6),
+                             max_new=args.max_new, seed=args.seed)
 
     t0 = time.time()
-    done = engine.run()
+    done = serve(cfg, params, requests, slots=args.slots,
+                 max_len=args.max_len)
     dt = time.time() - t0
     toks = sum(len(r.generated) for r in done)
     print(f"served {len(done)} requests / {toks} tokens in {dt:.2f}s "

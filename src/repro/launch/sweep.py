@@ -1,11 +1,14 @@
 """The PaPaS driver: run a WDL parameter file where tasks are TRAINING
 RUNS of this framework — the paper's technique applied to itself.
 
-    PYTHONPATH=src python -m repro.launch.sweep examples/lr_sweep.yaml
+    PYTHONPATH=src python -m repro.launch.sweep study.yaml
 
 Tasks whose command starts with ``train`` are resolved to in-process
 training calls (registry execution); anything else runs as a shell
-command.  ``parallel: vmap-stack`` gang-packs stackable instances (same
+command.  The accelerator belongs to one process, so ``train`` tasks
+always run in this one: ``--pool process`` refuses them, and jax is
+imported only when the study has ``train`` tasks or ``--gang``.
+``parallel: vmap-stack`` gang-packs stackable instances (same
 arch/shape, different scalars) into ONE compiled program via
 ``repro.train.ensemble`` — the TPU realization of the paper's
 job-batching (§4.3).  ``--slots N --pool thread|process`` runs instances
@@ -44,18 +47,14 @@ import argparse
 import shlex
 import sys
 from pathlib import Path
-from typing import Any
+from typing import Any, Sequence
 
-import jax
-
-from repro.configs import get_smoke
 from repro.core import (
     GangExecutor, LocalSubmitter, LocalTransport, ResultsAggregator,
     SchedulerSubmitter, SSHTransport, Telemetry, WDLError, load_study,
     stackable_key,
 )
 from repro.launch import report as report_mod
-from repro.train.ensemble import train_ensemble
 
 
 def _train_combo(combo: dict[str, Any], defaults: dict[str, Any]) -> float:
@@ -76,7 +75,11 @@ def _window_arg(text: str) -> Any:
             f"window must be a positive int or 'auto', got {text!r}")
 
 
-def main() -> None:
+def main(argv: Sequence[str] | None = None) -> dict[str, Any]:
+    """Run a study; returns ``{"results", "dispatches"}``: the results
+    by instance id (empty under ``--report``) and the number of
+    training-program launches (one per gang group, or one per ``train``
+    task without ``--gang``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("paramfile", nargs="+")
     ap.add_argument("--resume", action="store_true")
@@ -173,7 +176,7 @@ def main() -> None:
                          "exit 1 on any error-severity rule — the same "
                          "checks 'python -m repro.launch.lint' runs")
     ap.add_argument("--root", default=".papas")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     try:
         study = load_study(*[Path(p) for p in args.paramfile],
@@ -210,6 +213,12 @@ def main() -> None:
 
     # registry: any task whose command begins with "train" runs in-process
     registry = {}
+    member_runs = [0]
+
+    def _member(combo, defaults):
+        member_runs[0] += 1
+        return _train_combo(combo, defaults)
+
     for tname, task in study.spec.tasks.items():
         if task.command and task.command.split()[0] == "train":
             defaults = dict(
@@ -217,8 +226,15 @@ def main() -> None:
                 (t.split("=", 1) for t in shlex.split(task.command)[1:]
                  if "=" in t))
             registry[tname] = (
-                lambda combo, _d=defaults: _train_combo(combo, _d))
+                lambda combo, _d=defaults: _member(combo, _d))
+    if registry and args.pool == "process":
+        ap.error("--pool process cannot run 'train' tasks: the accelerator "
+                 "belongs to one process, so they run in this one "
+                 "(use --pool inline or --gang)")
     study.registry.update(registry)
+    if registry or args.gang:
+        from repro.launch.mesh import enable_compile_cache
+        enable_compile_cache()
 
     counts = {"ok": 0, "total": 0}
     extra_kwargs: dict = {}
@@ -265,11 +281,13 @@ def main() -> None:
 
     if args.gang:
         def gang_runner(nodes):
+            from repro.train.ensemble import train_ensemble
             members = [dict(n.combo) for n in nodes]
             return train_ensemble(members)
         gang = GangExecutor(stackable_key, gang_runner)
         results = study.run(gang=gang, resume=args.resume,
                             window=args.window, **extra_kwargs)
+        dispatches = gang.stats.dispatches
         print(f"[gang] {gang.stats.tasks} tasks in "
               f"{gang.stats.dispatches} dispatches "
               f"(batching ×{gang.stats.batching_factor:.0f})")
@@ -294,6 +312,11 @@ def main() -> None:
                                 **extra_kwargs)
         except ValueError as e:
             ap.error(str(e))    # e.g. unknown --pool kind, missing hosts
+        dispatches = member_runs[0]
+        if registry:
+            print(f"[train] {dispatches} member dispatches")
+    outcome = {"results": results if aggregator is None else {},
+               "dispatches": dispatches}
 
     if tel is not None:
         if args.status:
@@ -326,7 +349,7 @@ def main() -> None:
         # offline twin reads records.jsonl via repro.launch.report)
         print(report_mod.runtime_report(study.db, args.group_by or "task",
                                         args.report_format))
-        return
+        return outcome
     if aggregator is not None:
         for key, err in aggregator.key_errors.items():
             print(f"warning: group-by key {key!r}: {err}",
@@ -343,12 +366,13 @@ def main() -> None:
                 baseline, args.report_format))
         except (KeyError, ValueError) as e:
             ap.error(str(e))    # e.g. missing baseline, bad group key
-        return
+        return outcome
 
     for rid, res in sorted(results.items()):
         val = res.value if res.value is not None else ""
         where = f" @{res.host}" if res.host else ""
         print(f"  {rid}: {res.status} ({res.runtime:.2f}s){where} {val}")
+    return outcome
 
 
 def _wdl_baseline(spec) -> dict | None:

@@ -1,33 +1,112 @@
 """End-to-end training driver.
 
-    PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b \\
+    PYTHONPATH=src python -m repro.launch.train --arch h2o-danube-1.8b \\
         --smoke --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/run1
 
-Runs on whatever devices exist (CPU here, a pod in production): builds
-the mesh, sharded train state, data stream, jit'd train step; checkpoints
-every ``--ckpt-every`` steps and resumes from the latest checkpoint when
-restarted — kill it mid-run and rerun the same command to see the
-fault-tolerance path.
+Runs on whatever devices exist (CPU in tests, a TPU host in
+production): builds the mesh, the sharded train state (initialized on
+the devices under its shardings), the data stream and the jitted train
+step; checkpoints every ``--ckpt-every`` steps and resumes from the
+latest checkpoint when restarted — kill it mid-run and rerun the same
+command to see the fault-tolerance path.
 """
 from __future__ import annotations
 
 import argparse
-import dataclasses
+import functools
 import time
+from typing import Any
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import ckpt
-from repro.jax_compat import set_mesh
 from repro.configs import get, get_smoke
 from repro.data.pipeline import make_stream
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_local_mesh
+from repro.launch.mesh import enable_compile_cache, make_local_mesh
+from repro.models.config import ArchConfig
 from repro.optim.adamw import AdamW, cosine_schedule
 from repro.train.step import (
-    TrainStepConfig, init_train_state, make_train_step,
+    TrainStepConfig, abstract_train_state, init_train_state, make_train_step,
 )
+
+
+def train(cfg: ArchConfig, mesh: jax.sharding.Mesh, *, steps: int,
+          batch: int, seq: int, lr: float = 3e-4, warmup: int = 20,
+          seed: int = 0, n_micro: int = 1, ckpt_dir: str | None = None,
+          ckpt_every: int = 50, log_every: int = 10) -> dict[str, Any]:
+    """Train ``cfg`` on ``mesh`` up to global step ``steps``.
+
+    Returns the compile time, the first step's time, the mean time of
+    the later steps (each span ends in ``block_until_ready``), and the
+    loss of every step run.
+    """
+    opt = AdamW(schedule=cosine_schedule(lr, warmup, steps))
+    step_fn = make_train_step(cfg, opt, TrainStepConfig(n_micro=n_micro))
+    state_sh = shd.state_shardings(abstract_train_state(cfg, opt), mesh)
+    # initialize under the target shardings: each device materializes
+    # only its shard, so a state that fits only when sharded still fits
+    init = jax.jit(functools.partial(init_train_state, cfg, opt),
+                   out_shardings=state_sh)
+    state = init(jax.random.PRNGKey(seed))
+
+    start_step = 0
+    if ckpt_dir and ckpt.latest_step(ckpt_dir) is not None:
+        state = ckpt.restore(state, ckpt_dir, shardings=state_sh)
+        start_step = int(state["step"])
+        print(f"[restore] resumed from step {start_step}")
+
+    stream = iter(make_stream(cfg, batch, seq, seed=seed,
+                              start_step=start_step))
+    first = next(stream)
+    batch_sh = shd.batch_shardings(
+        jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), first),
+        mesh)
+    replicated = NamedSharding(mesh, P())
+
+    with jax.set_mesh(mesh):
+        t0 = time.perf_counter()
+        jit_step = jax.jit(step_fn, in_shardings=(state_sh, batch_sh),
+                           out_shardings=(state_sh, replicated),
+                           donate_argnums=(0,))
+        compiled = jit_step.lower(state, jax.device_put(first, batch_sh)
+                                  ).compile()
+        compile_s = time.perf_counter() - t0
+
+        losses, marks = [], []
+        t_run = time.perf_counter()
+        for step in range(start_step, steps):
+            host_batch = first if step == start_step else next(stream)
+            state, metrics = compiled(state,
+                                      jax.device_put(host_batch, batch_sh))
+            losses.append(metrics["loss"])
+            if step == start_step:
+                jax.block_until_ready(state)
+                marks.append(time.perf_counter())
+            if step % log_every == 0 or step == steps - 1:
+                dt = time.perf_counter() - t_run
+                tok = (step - start_step + 1) * batch * seq
+                print(f"step {step:5d} loss={float(metrics['loss']):.4f} "
+                      f"ce={float(metrics['ce']):.4f} "
+                      f"gnorm={float(metrics['grad_norm']):.3f} "
+                      f"lr={float(metrics['lr']):.2e} "
+                      f"tok/s={tok / max(dt, 1e-9):,.0f}")
+            if ckpt_dir and (step + 1) % ckpt_every == 0:
+                path = ckpt.save(state, ckpt_dir, step + 1)
+                print(f"[ckpt] saved {path}")
+        jax.block_until_ready(state)
+        marks.append(time.perf_counter())
+
+    if ckpt_dir:
+        ckpt.save(state, ckpt_dir, int(state["step"]))
+    n = len(losses)
+    return {
+        "compile_s": compile_s,
+        "first_step_s": marks[0] - t_run if n else None,
+        "steady_step_s": (marks[-1] - marks[0]) / (n - 1) if n > 1 else None,
+        "losses": [float(x) for x in losses],
+    }
 
 
 def main() -> None:
@@ -47,51 +126,16 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)
-    mesh = make_local_mesh()
-    opt = AdamW(schedule=cosine_schedule(args.lr, args.warmup, args.steps))
-    step_fn = make_train_step(cfg, opt,
-                              TrainStepConfig(n_micro=args.n_micro))
-
-    state = init_train_state(cfg, opt, jax.random.PRNGKey(args.seed))
-    state_sh = shd.state_shardings(
-        jax.eval_shape(lambda s: s, state), mesh)
-    state = jax.device_put(state, state_sh)
-
-    start_step = 0
-    if args.ckpt_dir and ckpt.latest_step(args.ckpt_dir) is not None:
-        state = ckpt.restore(state, args.ckpt_dir, shardings=state_sh)
-        start_step = int(state["step"])
-        print(f"[restore] resumed from step {start_step}")
-
-    stream = make_stream(cfg, args.batch, args.seq, seed=args.seed,
-                         start_step=start_step)
-    batch_sh = None
-    jit_step = jax.jit(step_fn, donate_argnums=(0,))
-
-    t0 = time.time()
-    tokens = 0
-    with set_mesh(mesh):
-        for i, host_batch in enumerate(stream):
-            step = start_step + i
-            if step >= args.steps:
-                break
-            batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
-            state, metrics = jit_step(state, batch)
-            tokens += args.batch * args.seq
-            if step % args.log_every == 0 or step == args.steps - 1:
-                dt = time.time() - t0
-                print(f"step {step:5d} loss={float(metrics['loss']):.4f} "
-                      f"ce={float(metrics['ce']):.4f} "
-                      f"gnorm={float(metrics['grad_norm']):.3f} "
-                      f"lr={float(metrics['lr']):.2e} "
-                      f"tok/s={tokens / max(dt, 1e-9):,.0f}")
-            if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
-                path = ckpt.save(state, args.ckpt_dir, step + 1)
-                print(f"[ckpt] saved {path}")
-    if args.ckpt_dir:
-        ckpt.save(state, args.ckpt_dir, int(state["step"]))
-    print(f"done: final loss {float(metrics['loss']):.4f}")
+    out = train(cfg, make_local_mesh(), steps=args.steps, batch=args.batch,
+                seq=args.seq, lr=args.lr, warmup=args.warmup,
+                seed=args.seed, n_micro=args.n_micro,
+                ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
+                log_every=args.log_every)
+    if out["losses"]:
+        print(f"done: final loss {out['losses'][-1]:.4f} "
+              f"(compile {out['compile_s']:.1f}s)")
 
 
 if __name__ == "__main__":

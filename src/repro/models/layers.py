@@ -11,8 +11,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.jax_compat import mesh_axis_names
-
 
 def cast(x: jax.Array, dtype: Any) -> jax.Array:
     return x.astype(dtype) if x.dtype != jnp.dtype(dtype) else x
@@ -22,7 +20,7 @@ def maybe_shard(x: jax.Array, *entries: Any) -> jax.Array:
     """Sharding constraint against the ambient abstract mesh; no-op when
     no mesh (or no "model" axis) is active — keeps model code usable on
     a single device and fully sharded under an active mesh."""
-    names = mesh_axis_names()
+    names = jax.sharding.get_abstract_mesh().axis_names
     if "model" not in names:
         return x
     fixed = tuple(e if (e is None or (isinstance(e, str) and e in names)

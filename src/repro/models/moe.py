@@ -22,8 +22,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.jax_compat import get_abstract_mesh, mesh_axis_names, shard_map
-
 from .layers import _act, cast, maybe_shard
 
 
@@ -262,8 +260,8 @@ def moe_ragged_sharded(
     shard; one psum over "model" combines the ffm partial sums.  Per
     layer this costs one AG(x) + one psum(out) instead of the einsum
     dispatch's O(E·C) traffic — and zero dispatch FLOPs."""
-    am = get_abstract_mesh()
-    names = getattr(am, "axis_names", None) or ()
+    am = jax.sharding.get_abstract_mesh()
+    names = am.axis_names
     dp = tuple(a for a in ("pod", "data") if a in names)
     dp_entry = dp if len(dp) > 1 else (dp[0] if dp else None)
     P_ = jax.sharding.PartitionSpec
@@ -281,7 +279,7 @@ def moe_ragged_sharded(
             aux = jax.tree.map(lambda v: jax.lax.pmean(v, dp), aux)
         return out.reshape(b_loc, s, d).astype(x_loc.dtype), aux
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=am,
         in_specs=(P_(dp_entry, None, None), P_(None, None),
                   P_(None, None, "model"), P_(None, None, "model"),
@@ -311,7 +309,7 @@ def moe_block(
     """Full MoE FFN: routed experts (+ optional fused shared expert)."""
     b, s, d = x.shape
     flat = x.reshape(b * s, d)
-    am_names = mesh_axis_names()
+    am_names = jax.sharding.get_abstract_mesh().axis_names
     if dispatch == "ragged" and "model" in am_names:
         routed_bsd, aux = moe_ragged_sharded(
             x, p, n_experts=n_experts, top_k=top_k, act=act,

@@ -53,6 +53,8 @@ class ServeEngine:
         self.active: list[Request | None] = [None] * slots
         self.queue: list[Request] = []
         self.tokens = np.zeros((slots, 1), np.int32)
+        #: host copy of the latest tick's (slots, vocab) logits
+        self.last_logits: np.ndarray | None = None
 
     def submit(self, req: Request) -> None:
         self.queue.append(req)
@@ -74,7 +76,7 @@ class ServeEngine:
             return []
         logits, self.cache = self._step(
             self.params, self.cache, jnp.asarray(self.tokens))
-        logits = np.asarray(logits)
+        logits = self.last_logits = np.asarray(logits)
         finished: list[Request] = []
         for i, req in enumerate(self.active):
             if req is None:
@@ -93,8 +95,13 @@ class ServeEngine:
                 self.active[i] = None
         return finished
 
-    def run(self) -> list[Request]:
+    def run(self, on_tick: Callable[["ServeEngine"], None] | None = None
+            ) -> list[Request]:
+        """Tick until every request is answered; ``on_tick(self)`` runs
+        after each tick."""
         done: list[Request] = []
         while self.queue or any(self.active):
             done.extend(self.step())
+            if on_tick is not None:
+                on_tick(self)
         return done

@@ -183,3 +183,13 @@ class TestMoEDispatchEquivalence:
         le, _ = jax.jit(lambda p, b: m_e.loss(p, b))(params, batch)
         lr_, _ = jax.jit(lambda p, b: m_r.loss(p, b))(params, batch)
         assert abs(float(le) - float(lr_)) < 5e-3
+
+
+class TestInterpretDefault:
+    @pytest.mark.parametrize("backend,want", [
+        ("cpu", True), ("tpu", False), ("gpu", False)])
+    def test_interpret_only_on_cpu(self, backend, want, monkeypatch):
+        """Only the CPU interprets; any other backend compiles the real
+        kernels, so a device that cannot run them fails loudly."""
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert ops._interpret_default() is want

@@ -6,9 +6,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import get_smoke
-from repro.jax_compat import set_mesh
 from repro.models import Model, synthetic_batch
 from repro.models.moe import moe_ragged, moe_sorted_local
 
@@ -73,8 +73,9 @@ class TestShardMapPath:
         m = Model(cfg)
         params = m.init(KEY)
         batch = synthetic_batch(cfg, 2, 32, KEY)
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
-        with set_mesh(mesh):
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        with jax.set_mesh(mesh):
             loss, aux = jax.jit(lambda p, b: m.loss(p, b))(params, batch)
         assert bool(jnp.isfinite(loss))
         # agrees with the local (no-mesh) ragged path

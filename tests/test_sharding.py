@@ -3,7 +3,7 @@ functions over paths/shapes + a mesh object built from 1 device)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs import get_smoke
 from repro.distributed import sharding as shd
@@ -13,7 +13,8 @@ from repro.models import Model
 @pytest.fixture(scope="module")
 def mesh():
     # single real device, axis sizes 1: rule structure is what we test
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def specs_by_suffix(tree, mesh):
@@ -121,3 +122,18 @@ class TestCacheRules:
         kv_specs = {tuple(sh.spec) for path, sh in flat
                     if shd._path_names(path)[-1] in ("k", "v")}
         assert kv_specs    # non-empty; structure validated
+
+
+class TestMesh:
+    def test_local_mesh_axes_are_auto(self):
+        from jax.sharding import AxisType
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(devices=jax.devices()[:1])
+        assert mesh.axis_names == ("data", "model")
+        assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
+
+    def test_peaks_keyed_by_device_kind(self):
+        from repro.launch.mesh import peaks
+        assert peaks("TPU v5 lite")["flops_bf16"] == 197e12
+        with pytest.raises(KeyError, match="no published peaks"):
+            peaks("cpu")
