@@ -11,7 +11,6 @@ import traceback
 
 from . import (
     engine_overhead, fig1_schedules, fig34_grouping, fig56_matmul_study,
-    roofline,
 )
 
 SUITES = {
@@ -19,7 +18,6 @@ SUITES = {
     "fig34": fig34_grouping.run,
     "fig56": fig56_matmul_study.run,
     "engine": engine_overhead.run,
-    "roofline": roofline.run,
 }
 
 

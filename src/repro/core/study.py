@@ -650,36 +650,38 @@ class ParameterStudy:
                 retry=retry, chaos=chaos, trace=trace)
         ctrl = self._resolve_chaos(chaos)
         tel = self._resolve_trace(trace)
-        instances = self.instances()
-        completed: set[str] = set()
-        if resume and self.journal.exists():
-            state = self.journal.load_state()
-            completed = set(state.completed)
-            if state.version == 1 and state.instances:
-                instances = state.instances
-            elif state.version == 2 and state.completed_indices:
-                space = self.space()
-                if state.space_hash and state.space_hash != space.space_hash():
-                    raise ValueError(
-                        f"cannot resume: journal was written for space "
-                        f"{state.space_hash} but this study declares "
-                        f"{space.space_hash()}")
-                completed |= self._ids_from_indices(
-                    space, state.completed_indices)
-        dag = self.build_dag(instances)
-        self.db.write_meta({
-            "name": self.name,
-            "n_instances": len(instances),
-            "n_tasks": len(self.spec.tasks),
-            "n_nodes": len(dag.nodes),
-            "started": time.time(),
-        })
-        run_fn = runner or self._default_runner
-        host_map: dict[str, str] = {}
-        if resume:
-            host_map.update(self.journal.hosts())
-        self.journal.save(instances, completed, {"name": self.name},
-                          hosts=host_map)
+        with telemetry_mod.span("study.plan", tel):
+            instances = self.instances()
+            completed: set[str] = set()
+            if resume and self.journal.exists():
+                state = self.journal.load_state()
+                completed = set(state.completed)
+                if state.version == 1 and state.instances:
+                    instances = state.instances
+                elif state.version == 2 and state.completed_indices:
+                    space = self.space()
+                    if (state.space_hash
+                            and state.space_hash != space.space_hash()):
+                        raise ValueError(
+                            f"cannot resume: journal was written for space "
+                            f"{state.space_hash} but this study declares "
+                            f"{space.space_hash()}")
+                    completed |= self._ids_from_indices(
+                        space, state.completed_indices)
+            dag = self.build_dag(instances)
+            self.db.write_meta({
+                "name": self.name,
+                "n_instances": len(instances),
+                "n_tasks": len(self.spec.tasks),
+                "n_nodes": len(dag.nodes),
+                "started": time.time(),
+            })
+            run_fn = runner or self._default_runner
+            host_map: dict[str, str] = {}
+            if resume:
+                host_map.update(self.journal.hosts())
+            self.journal.save(instances, completed, {"name": self.name},
+                              hosts=host_map)
 
         # arm chaos + telemetry for the backend's whole lifetime — lane
         # pools capture both at construction, transports consult chaos
@@ -748,8 +750,9 @@ class ParameterStudy:
             # strand a journaled completion without its record
             self.journal.set_pre_flush(self.db.flush)
             self._run_base_env = dict(os.environ)  # one snapshot per run
-            with self.journal.group_commit(self.flush_count,
-                                           self.flush_interval), \
+            with telemetry_mod.span("study.schedule"), \
+                    self.journal.group_commit(self.flush_count,
+                                              self.flush_interval), \
                     self.db.group_commit(self.flush_count,
                                          self.flush_interval):
                 results = sched.execute(dag, run_fn, completed=completed,
@@ -762,18 +765,20 @@ class ParameterStudy:
             self.journal.set_pre_flush(None)
             if owned and worker is not None:
                 worker.shutdown()
-        # compact the journal: fold the append log back into the base
-        self.journal.save(instances, completed, {"name": self.name},
-                          hosts=host_map)
+        with telemetry_mod.span("study.record", tel):
+            # compact the journal: fold the append log back into the base
+            self.journal.save(instances, completed, {"name": self.name},
+                              hosts=host_map)
+            self.last_run_stats = {
+                "peak_live_nodes": sched.peak_live_nodes,
+                "n_instances": len(instances),
+            }
+            self.last_run_stats.update(
+                self._finalize_run_health(worker, ctrl))
         if tel is not None:
             self._finalize_telemetry(tel)
         self.journal.set_shards(1)
         self.db.set_shards(1)
-        self.last_run_stats = {
-            "peak_live_nodes": sched.peak_live_nodes,
-            "n_instances": len(instances),
-        }
-        self.last_run_stats.update(self._finalize_run_health(worker, ctrl))
         return results
 
     def _run_windowed(
@@ -802,49 +807,52 @@ class ParameterStudy:
         """Streaming execution: windowed admission + journal v2."""
         ctrl = self._resolve_chaos(chaos)
         tel = self._resolve_trace(trace)
-        space = self.space()
-        shash = space.space_hash()
-        n_instances = space.sample_count()
-        if space.size():
-            # every instance shares one task topology — validate it once
-            # on a template sub-DAG instead of per admission
-            template = TaskDAG()
-            for node in self._instance_nodes(space.combo_at(0)):
-                template.add(node)
-            template.validate()
+        with telemetry_mod.span("study.plan", tel):
+            space = self.space()
+            shash = space.space_hash()
+            n_instances = space.sample_count()
+            if space.size():
+                # every instance shares one task topology — validate it
+                # once on a template sub-DAG instead of per admission
+                template = TaskDAG()
+                for node in self._instance_nodes(space.combo_at(0)):
+                    template.add(node)
+                template.validate()
 
-        completed_idx: dict[str, set[int]] = {}
-        host_map: dict[str, str] = {}
-        if resume and self.journal.exists():
-            state = self.journal.load_state()
-            if state.version == 2:
-                if state.space_hash and state.space_hash != shash:
-                    raise ValueError(
-                        f"cannot resume: journal was written for space "
-                        f"{state.space_hash} but this study declares "
-                        f"{shash}")
-                completed_idx = {t: set(ix) for t, ix
-                                 in (state.completed_indices or {}).items()}
-            else:
-                completed_idx = self._indices_from_v1(
-                    space, state.instances or [], state.completed)
-            host_map.update(state.hosts)
+            completed_idx: dict[str, set[int]] = {}
+            host_map: dict[str, str] = {}
+            if resume and self.journal.exists():
+                state = self.journal.load_state()
+                if state.version == 2:
+                    if state.space_hash and state.space_hash != shash:
+                        raise ValueError(
+                            f"cannot resume: journal was written for space "
+                            f"{state.space_hash} but this study declares "
+                            f"{shash}")
+                    completed_idx = {
+                        t: set(ix) for t, ix
+                        in (state.completed_indices or {}).items()}
+                else:
+                    completed_idx = self._indices_from_v1(
+                        space, state.instances or [], state.completed)
+                host_map.update(state.hosts)
 
-        self.db.write_meta({
-            "name": self.name,
-            "n_instances": n_instances,
-            "n_tasks": len(self.spec.tasks),
-            "n_nodes": n_instances * len(self.spec.tasks),
-            "space": shash,
-            "window": window,
-            "started": time.time(),
-        })
-        self.journal.save_indexed(shash, n_instances, completed_idx,
-                                  {"name": self.name}, hosts=host_map)
+            self.db.write_meta({
+                "name": self.name,
+                "n_instances": n_instances,
+                "n_tasks": len(self.spec.tasks),
+                "n_nodes": n_instances * len(self.spec.tasks),
+                "space": shash,
+                "window": window,
+                "started": time.time(),
+            })
+            self.journal.save_indexed(shash, n_instances, completed_idx,
+                                      {"name": self.name}, hosts=host_map)
 
-        source = InstanceWindow(self, space=space, completed=completed_idx)
-        dag = TaskDAG()
-        run_fn = runner or self._default_runner
+            source = InstanceWindow(self, space=space,
+                                    completed=completed_idx)
+            dag = TaskDAG()
+            run_fn = runner or self._default_runner
 
         # see the eager path: arm chaos + telemetry for the backend's
         # lifetime
@@ -916,8 +924,9 @@ class ParameterStudy:
             self.db.set_shards(shards)
             self.journal.set_pre_flush(self.db.flush)
             self._run_base_env = dict(os.environ)  # one snapshot per run
-            with self.journal.group_commit(self.flush_count,
-                                           self.flush_interval), \
+            with telemetry_mod.span("study.schedule"), \
+                    self.journal.group_commit(self.flush_count,
+                                              self.flush_interval), \
                     self.db.group_commit(self.flush_count,
                                          self.flush_interval):
                 results = sched.execute(dag, run_fn, on_result=_on_result,
@@ -931,23 +940,25 @@ class ParameterStudy:
             self.journal.set_pre_flush(None)
             if owned and worker is not None:
                 worker.shutdown()
-        # compact: fold the append log back into a fresh v2 base
-        self.journal.save_indexed(shash, n_instances, completed_idx,
-                                  {"name": self.name}, hosts=host_map)
+        with telemetry_mod.span("study.record", tel):
+            # compact: fold the append log back into a fresh v2 base
+            self.journal.save_indexed(shash, n_instances, completed_idx,
+                                      {"name": self.name}, hosts=host_map)
+            self.last_run_stats = {
+                "peak_live_nodes": sched.peak_live_nodes,
+                "n_instances": n_instances,
+                "admitted_instances": source.admitted,
+                "skipped_complete": source.skipped,
+                "slots": slots,   # post-lift: the admission bound's slots
+                "window": win.current if isinstance(win, AdaptiveWindow)
+                else window,
+            }
+            self.last_run_stats.update(
+                self._finalize_run_health(worker, ctrl))
         if tel is not None:
             self._finalize_telemetry(tel)
         self.journal.set_shards(1)
         self.db.set_shards(1)
-        self.last_run_stats = {
-            "peak_live_nodes": sched.peak_live_nodes,
-            "n_instances": n_instances,
-            "admitted_instances": source.admitted,
-            "skipped_complete": source.skipped,
-            "slots": slots,     # post-lift: the admission bound's slots
-            "window": win.current if isinstance(win, AdaptiveWindow)
-            else window,
-        }
-        self.last_run_stats.update(self._finalize_run_health(worker, ctrl))
         return results
 
 

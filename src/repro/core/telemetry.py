@@ -35,11 +35,31 @@ engine pays one identity check per seam and nothing else.
   ``/metrics`` (Prometheus) and ``/status`` (JSON) — the seam a
   future study service grows into.
 
+* **Program spans** (:func:`span`) — the layer boundaries of the study
+  path: ``papas.study.plan``, ``.schedule`` and ``.record``
+  (``ParameterStudy.run``), and
+  ``papas.gang.build``, ``.lower``, ``.compile`` and ``.run``
+  (``train_ensemble``).  Each is a ``jax.profiler.TraceAnnotation``
+  whenever jax is already imported, so it lands in a profiler trace on
+  the profiler's own clock, beside the device operations; when armed it
+  is also a ``span:<thread>`` slice in ``trace.json`` and an
+  observation of ``papas_span_seconds{name=...}``.  The last closed
+  spans stay readable in-process through :func:`recent_spans`, armed
+  or not.
+
 Arm a run with ``ParameterStudy.run(trace=...)``, ``sweep.py
 --trace``, or ``PAPAS_TRACE=1`` (or ``PAPAS_TRACE=/path/trace.json``)
 in the environment.  Emission uses explicit caller-supplied timestamps
 (the scheduler passes its own ``clock()`` readings), so traces from
 ``VirtualClock`` runs carry exact virtual timings.
+
+Clocks: wall-clock components stamp ``trace.json`` with
+``time.monotonic``; the profiler stamps its trace with the Unix-epoch
+clock (an ``.xplane.pb`` stores event times relative to its
+``profile_start_time``).  ``trace.json``'s ``otherData`` holds
+``profiler_clock_offset_us``, the epoch time less the monotonic time
+read once when the run was armed: add it to an event's ``ts`` to place
+the event on the profiler's clock.
 """
 from __future__ import annotations
 
@@ -48,7 +68,8 @@ import os
 import sys
 import threading
 import time
-from contextlib import contextmanager
+from collections import deque
+from contextlib import contextmanager, nullcontext
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
 from typing import Any, Iterator, TextIO
@@ -65,6 +86,8 @@ __all__ = [
     "activated",
     "current",
     "install",
+    "recent_spans",
+    "span",
 ]
 
 
@@ -176,16 +199,16 @@ class MetricsRegistry:
                     f"{type(m).__name__}, not {cls.__name__}")
             return m
 
-    def counter(self, name: str, **labels: Any) -> Counter:
+    def counter(self, name: str, /, **labels: Any) -> Counter:
         return self._get(Counter, name, labels)
 
-    def gauge(self, name: str, **labels: Any) -> Gauge:
+    def gauge(self, name: str, /, **labels: Any) -> Gauge:
         return self._get(Gauge, name, labels)
 
-    def histogram(self, name: str, **labels: Any) -> Histogram:
+    def histogram(self, name: str, /, **labels: Any) -> Histogram:
         return self._get(Histogram, name, labels)
 
-    def value(self, name: str, **labels: Any) -> Any:
+    def value(self, name: str, /, **labels: Any) -> Any:
         """Current value of a series (0 when never touched)."""
         with self._lock:
             m = self._metrics.get(_full_name(name, labels))
@@ -278,6 +301,8 @@ class TraceCollector:
         self._lock = threading.Lock()
         self._events: list[dict[str, Any]] = []
         self._tids: dict[str, int] = {}
+        #: Unix-epoch (profiler) time less monotonic time, in µs
+        self.clock_offset_us = (time.time_ns() - time.monotonic_ns()) / 1e3
 
     def _tid(self, track: str) -> int:
         # caller holds self._lock
@@ -344,7 +369,10 @@ class TraceCollector:
     def write(self, path: str | Path) -> Path:
         """Serialize as ``{"traceEvents": [...]}`` (Perfetto-loadable)."""
         path = Path(path)
-        doc = {"traceEvents": self.events(), "displayTimeUnit": "ms"}
+        doc = {"traceEvents": self.events(), "displayTimeUnit": "ms",
+               "otherData": {"clock": "monotonic",
+                             "profiler_clock_offset_us":
+                                 self.clock_offset_us}}
         path.write_text(json.dumps(doc) + "\n")
         return path
 
@@ -546,3 +574,53 @@ def activated(tel: Telemetry) -> Iterator[Telemetry]:
         yield tel
     finally:
         install(prev)
+
+
+# ---------------------------------------------------------------------------
+# program spans
+
+#: the last closed spans of this process, ``(name, start, end)`` on
+#: ``time.monotonic``: the seam through which an in-process reader (the
+#: chip benchmark's ``study_*`` span metrics) sums them without a
+#: profile. Spans are per study phase and per gang call, so a bounded
+#: ring holds every one of a long run's recent studies
+_recent: deque[tuple[str, float, float]] = deque(maxlen=4096)
+_recent_lock = threading.Lock()
+
+
+def recent_spans() -> list[tuple[str, float, float]]:
+    """The last closed spans of this process, oldest first, armed or
+    not: what an in-process reader (a notebook, a benchmark) can sum."""
+    with _recent_lock:
+        return list(_recent)
+
+
+@contextmanager
+def span(name: str, tel: Telemetry | None = None) -> Iterator[None]:
+    """Span ``papas.<name>`` over the enclosed block.
+
+    A ``jax.profiler.TraceAnnotation`` when jax is already imported
+    (never imported here, so shell-only studies stay jax-free); on
+    close, a slice on the thread's ``span:`` track and an observation of
+    ``papas_span_seconds{name=papas.<name>}`` in ``tel``, or in the
+    armed run's telemetry when ``tel`` is None.
+    """
+    full = f"papas.{name}"
+    jax = sys.modules.get("jax")
+    with (jax.profiler.TraceAnnotation(full) if jax is not None
+          else nullcontext()):
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            t1 = time.monotonic()
+            with _recent_lock:
+                _recent.append((full, t0, t1))
+            if tel is None:
+                tel = current()
+            if tel is not None:
+                tel.trace.complete(
+                    f"span:{threading.current_thread().name}", full, t0,
+                    t1, cat="span")
+                tel.metrics.histogram("papas_span_seconds",
+                                      name=full).observe(t1 - t0)

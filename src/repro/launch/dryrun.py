@@ -319,8 +319,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     chips = mesh_chips(mesh)
     # NOTE: gradient accumulation (n_micro>1) currently triggers GSPMD
     # "involuntary full rematerialization" on the microbatch reshape
-    # (XLA b/433785288); >HBM cells are documented in EXPERIMENTS.md with
-    # the production mitigation (Pallas flash kernels on real TPU).
+    # (XLA b/433785288).
     t0 = time.time()
     lowered, compiled = _lower_fn(shape.kind)(cfg, shape, mesh)
     compile_s = time.time() - t0

@@ -213,46 +213,53 @@ def layer_body(
     moe_groups: int,
     cache: dict[str, jax.Array] | None = None,
 ) -> tuple[jax.Array, dict[str, jax.Array], Any]:
-    """One layer: returns (x, aux, new_cache)."""
+    """One layer: returns (x, aux, new_cache).
+
+    Named scopes ``mixer`` (norm1, the attention, SSD or hybrid block
+    and its residual add) and ``ffn`` (norm2, the MLP or MoE block and
+    its residual add) name the layer's device work in profiles."""
     eps = cfg.norm_eps
     aux = ZERO_AUX()
     new_cache = None
-    h = _norm(x, lp["norm1"], eps)
+    with jax.named_scope("mixer"):
+        h = _norm(x, lp["norm1"], eps)
 
-    if kind == "ssm":
-        y, new_cache = mamba2_block(
-            h, lp["ssm"], d_inner=cfg.d_inner, state_dim=cfg.ssm_state,
-            head_dim=cfg.ssm_head_dim, n_groups=cfg.ssm_groups,
-            conv_width=cfg.ssm_conv, chunk=cfg.ssm_chunk,
-            compute_dtype=cfg.compute_dtype,
-            cache=cache, use_kernels=cfg.use_kernels)
-        return x + y.astype(x.dtype), aux, new_cache
+        if kind == "ssm":
+            y, new_cache = mamba2_block(
+                h, lp["ssm"], d_inner=cfg.d_inner, state_dim=cfg.ssm_state,
+                head_dim=cfg.ssm_head_dim, n_groups=cfg.ssm_groups,
+                conv_width=cfg.ssm_conv, chunk=cfg.ssm_chunk,
+                compute_dtype=cfg.compute_dtype,
+                cache=cache, use_kernels=cfg.use_kernels)
+            return x + y.astype(x.dtype), aux, new_cache
 
-    if kind in ("hyb_g", "hyb_l"):
-        attn_cache = cache["attn"] if cache is not None else None
-        ssm_cache = cache["ssm"] if cache is not None else None
-        a_out, new_attn_cache = _attn_sublayer(cfg, kind, h, lp, positions,
-                                               attn_cache)
-        s_out, new_ssm_cache = mamba2_block(
-            h, lp["ssm"], d_inner=cfg.d_inner, state_dim=cfg.ssm_state,
-            head_dim=cfg.ssm_head_dim, n_groups=cfg.ssm_groups,
-            conv_width=cfg.ssm_conv, chunk=cfg.ssm_chunk,
-            compute_dtype=cfg.compute_dtype,
-            cache=ssm_cache, use_kernels=cfg.use_kernels)
-        # Hymba output fusion: mean of per-branch normalized outputs
-        y = 0.5 * (rms_norm(a_out, lp["branch_norm_attn"], eps)
-                   + rms_norm(s_out.astype(a_out.dtype),
-                              lp["branch_norm_ssm"], eps))
-        x = x + y.astype(x.dtype)
-        if cache is not None:
-            new_cache = {"attn": new_attn_cache, "ssm": new_ssm_cache}
-    else:
-        a_out, new_cache = _attn_sublayer(cfg, kind, h, lp, positions, cache)
-        x = x + a_out.astype(x.dtype)
+        if kind in ("hyb_g", "hyb_l"):
+            attn_cache = cache["attn"] if cache is not None else None
+            ssm_cache = cache["ssm"] if cache is not None else None
+            a_out, new_attn_cache = _attn_sublayer(cfg, kind, h, lp,
+                                                   positions, attn_cache)
+            s_out, new_ssm_cache = mamba2_block(
+                h, lp["ssm"], d_inner=cfg.d_inner, state_dim=cfg.ssm_state,
+                head_dim=cfg.ssm_head_dim, n_groups=cfg.ssm_groups,
+                conv_width=cfg.ssm_conv, chunk=cfg.ssm_chunk,
+                compute_dtype=cfg.compute_dtype,
+                cache=ssm_cache, use_kernels=cfg.use_kernels)
+            # Hymba output fusion: mean of per-branch normalized outputs
+            y = 0.5 * (rms_norm(a_out, lp["branch_norm_attn"], eps)
+                       + rms_norm(s_out.astype(a_out.dtype),
+                                  lp["branch_norm_ssm"], eps))
+            x = x + y.astype(x.dtype)
+            if cache is not None:
+                new_cache = {"attn": new_attn_cache, "ssm": new_ssm_cache}
+        else:
+            a_out, new_cache = _attn_sublayer(cfg, kind, h, lp, positions,
+                                              cache)
+            x = x + a_out.astype(x.dtype)
 
-    h2 = _norm(x, lp["norm2"], eps)
-    f_out, aux = _ffn_sublayer(cfg, kind, h2, lp, moe_groups)
-    return x + f_out.astype(x.dtype), aux, new_cache
+    with jax.named_scope("ffn"):
+        h2 = _norm(x, lp["norm2"], eps)
+        f_out, aux = _ffn_sublayer(cfg, kind, h2, lp, moe_groups)
+        return x + f_out.astype(x.dtype), aux, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -302,7 +309,8 @@ def backbone(
             return t
         return jax.lax.with_sharding_constraint(t, seq_spec)
 
-    x = _constrain(_embed_inputs(cfg, params, batch))
+    with jax.named_scope("embed"):
+        x = _constrain(_embed_inputs(cfg, params, batch))
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     aux_total = ZERO_AUX()
@@ -317,7 +325,8 @@ def backbone(
         body = _remat(cfg, seg_body)
         (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), seg_params)
 
-    x = _norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        x = _norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux_total
 
 
@@ -366,38 +375,43 @@ def loss_fn(
 
     With ``cfg.loss_chunk`` the CE is computed over sequence chunks
     (unrolled + rematerialized) so the (B,S,V) logits are never resident
-    — the standard big-vocab memory fix."""
+    — the standard big-vocab memory fix.  Named scopes: ``embed`` (the
+    input lookup), ``mixer`` and ``ffn`` (each layer), ``head`` (the
+    final norm, the LM head and the cross entropy)."""
     x, aux = backbone(cfg, params, batch, moe_groups, seq_spec)
-    labels = batch["labels"]
-    head = _head(cfg, params)
-    if seq_spec is not None and hasattr(seq_spec, "mesh"):
-        # pin the (d, V) head so the CE-scan grad accumulator stays
-        # vocab-sharded (GSPMD loses it through the tied-embed transpose)
-        from jax.sharding import NamedSharding, PartitionSpec
-        head = jax.lax.with_sharding_constraint(
-            head, NamedSharding(seq_spec.mesh, PartitionSpec(None, "model")))
-    b, s, d = x.shape
-    chunk = cfg.loss_chunk
-    if chunk and s > chunk and s % chunk == 0:
-        nc = s // chunk
-        xs = x.reshape(b, nc, chunk, d).transpose(1, 0, 2, 3)
-        ls = labels.reshape(b, nc, chunk).transpose(1, 0, 2)
+    with jax.named_scope("head"):
+        labels = batch["labels"]
+        head = _head(cfg, params)
+        if seq_spec is not None and hasattr(seq_spec, "mesh"):
+            # pin the (d, V) head so the CE-scan grad accumulator stays
+            # vocab-sharded (GSPMD loses it through the tied-embed
+            # transpose)
+            from jax.sharding import NamedSharding, PartitionSpec
+            head = jax.lax.with_sharding_constraint(
+                head,
+                NamedSharding(seq_spec.mesh, PartitionSpec(None, "model")))
+        b, s, d = x.shape
+        chunk = cfg.loss_chunk
+        if chunk and s > chunk and s % chunk == 0:
+            nc = s // chunk
+            xs = x.reshape(b, nc, chunk, d).transpose(1, 0, 2, 3)
+            ls = labels.reshape(b, nc, chunk).transpose(1, 0, 2)
 
-        def ce_body(acc, inp):
-            xc, lc = inp
-            return acc + _ce_terms(xc, head, lc, cfg.compute_dtype,
-                                   cfg.vocab_size), None
+            def ce_body(acc, inp):
+                xc, lc = inp
+                return acc + _ce_terms(xc, head, lc, cfg.compute_dtype,
+                                       cfg.vocab_size), None
 
-        nll_sum, _ = jax.lax.scan(jax.checkpoint(ce_body),
-                                  jnp.zeros((), jnp.float32), (xs, ls))
-    else:
-        nll_sum = _ce_terms(x, head, labels, cfg.compute_dtype,
-                            cfg.vocab_size)
-    denom = jnp.maximum((labels >= 0).sum(), 1).astype(jnp.float32)
-    ce = nll_sum / denom
-    loss = (ce
-            + 0.01 * aux["load_balance"]
-            + 0.001 * aux["router_z"])
+            nll_sum, _ = jax.lax.scan(jax.checkpoint(ce_body),
+                                      jnp.zeros((), jnp.float32), (xs, ls))
+        else:
+            nll_sum = _ce_terms(x, head, labels, cfg.compute_dtype,
+                                cfg.vocab_size)
+        denom = jnp.maximum((labels >= 0).sum(), 1).astype(jnp.float32)
+        ce = nll_sum / denom
+        loss = (ce
+                + 0.01 * aux["load_balance"]
+                + 0.001 * aux["router_z"])
     metrics = {"ce": ce, "loss": loss, **aux}
     return loss, metrics
 

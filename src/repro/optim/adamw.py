@@ -76,9 +76,12 @@ class AdamW:
                     params),
                 "count": jnp.zeros((), jnp.int32)}
 
+    @jax.named_scope("optimizer")
     def update(self, grads: Pytree, state: dict[str, Pytree],
                params: Pytree) -> tuple[Pytree, dict[str, Pytree],
                                         dict[str, jax.Array]]:
+        """One step, clipping included, in the named scope
+        ``optimizer``."""
         count = state["count"] + 1
         gnorm = global_norm(grads)
         if self.clip_norm:
