@@ -47,6 +47,12 @@ engine pays one identity check per seam and nothing else.
   spans stay readable in-process through :func:`recent_spans`, armed
   or not.
 
+* **Program tallies** (:func:`tally`) — counts of which path the
+  program took where it decides from what it observes, such as
+  ``papas.attn.kernel`` and ``papas.attn.xla`` (``attn_block``, once
+  per attention sub-layer traced); read in-process through
+  :func:`tallies`, armed or not.
+
 Arm a run with ``ParameterStudy.run(trace=...)``, ``sweep.py
 --trace``, or ``PAPAS_TRACE=1`` (or ``PAPAS_TRACE=/path/trace.json``)
 in the environment.  Emission uses explicit caller-supplied timestamps
@@ -88,6 +94,8 @@ __all__ = [
     "install",
     "recent_spans",
     "span",
+    "tallies",
+    "tally",
 ]
 
 
@@ -586,6 +594,8 @@ def activated(tel: Telemetry) -> Iterator[Telemetry]:
 #: ring holds every one of a long run's recent studies
 _recent: deque[tuple[str, float, float]] = deque(maxlen=4096)
 _recent_lock = threading.Lock()
+#: this process's program tallies, ``papas.<name>`` -> count
+_tallies: dict[str, int] = {}
 
 
 def recent_spans() -> list[tuple[str, float, float]]:
@@ -593,6 +603,19 @@ def recent_spans() -> list[tuple[str, float, float]]:
     not: what an in-process reader (a notebook, a benchmark) can sum."""
     with _recent_lock:
         return list(_recent)
+
+
+def tally(name: str) -> None:
+    """Count one ``papas.<name>`` event in this process."""
+    full = f"papas.{name}"
+    with _recent_lock:
+        _tallies[full] = _tallies.get(full, 0) + 1
+
+
+def tallies() -> dict[str, int]:
+    """This process's program tallies, armed or not."""
+    with _recent_lock:
+        return dict(_tallies)
 
 
 @contextmanager

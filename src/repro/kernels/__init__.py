@@ -1,6 +1,7 @@
 """Pallas TPU kernels for perf-critical hot spots (+ jnp oracles).
 
-flash_attention — blockwise GQA attention (causal / SWA / bidirectional)
+flash_attention — blockwise GQA attention (causal / SWA / bidirectional),
+                  with its own backward kernels
 ssd_scan        — Mamba2 SSD chunked scan
 grouped_matmul  — megablox-style ragged expert GEMM
 """

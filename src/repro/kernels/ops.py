@@ -21,25 +21,19 @@ def _interpret_default() -> bool:
     return jax.default_backend() == "cpu"
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_k: int = 128):
-    """Flash attention with automatic padding to block multiples."""
-    b, s, hq, d = q.shape
-    bq = min(block_q, max(16, s))
-    bk = min(block_k, max(16, s))
-    pad = (-s) % max(bq, bk)
+@functools.partial(jax.jit, static_argnames=("causal", "window"))
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
+    """Flash attention, differentiable, with the sequence padded to a
+    multiple of the kernel's block (``block_size(S)``)."""
+    s = q.shape[1]
+    block = _fa.block_size(s)
+    pad = (-s) % block
     if pad:
-        qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vp = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    else:
-        qp, kp, vp = q, k, v
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for x in (q, k, v))
     out = _fa.flash_attention(
-        qp, kp, vp, causal=causal, window=window,
-        block_q=bq, block_k=bk, interpret=_interpret_default(),
-        valid_len=s)
+        q, k, v, causal=causal, window=window, block=block,
+        interpret=_interpret_default(), valid_len=s)
     return out[:, :s] if pad else out
 
 

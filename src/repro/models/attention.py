@@ -1,12 +1,17 @@
 """Attention: GQA/MQA/MHA, causal + bidirectional, sliding-window.
 
-Three structural code paths (the XLA reference; the Pallas flash kernel
-replaces the inner computation on TPU when ``use_kernels``):
+Three structural XLA code paths:
 
 * ``full_attention``  — S×S masked attention (causal or bidirectional).
 * ``local_attention`` — chunk-banded SWA: each W-query chunk attends to
   its own and the previous chunk, so FLOPs scale as S·2W not S².
 * ``decode_attention``— one query against a KV cache.
+
+On a TPU the causal full-sequence case runs the Pallas flash kernel
+(``kernels/flash_attention.py``, with its own backward) instead of
+``full_attention``; ``flash_selected`` decides from what the code
+observes, and ``attn_block`` tallies each choice
+(``papas.attn.kernel`` / ``papas.attn.xla``, ``core/telemetry.py``).
 
 Shapes: q (B,S,Hq,D); k,v (B,S,Hkv,D); GQA groups Hq into Hkv bundles.
 """
@@ -16,6 +21,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .layers import apply_rope, cast, maybe_shard, rms_norm
 
@@ -215,6 +221,41 @@ def decode_attention(
 
 
 # ---------------------------------------------------------------------------
+# The Pallas flash kernel: where it applies
+# ---------------------------------------------------------------------------
+
+def flash_selected(*, backend: str, cached: bool, softcap: float, seq: int,
+                   model_axis: int) -> bool:
+    """Causal full-sequence attention runs the flash kernel on a TPU,
+    without a KV cache (training, forward), without a logit softcap
+    (the kernel has none), over at least one kernel block, with the
+    query heads whole on each device (``model`` mesh axis 1)."""
+    if backend != "tpu":          # Pallas (and its import) only on a TPU
+        return False
+    from repro.kernels.flash_attention import LANES
+    return (not cached and not softcap and seq >= LANES
+            and model_axis == 1)
+
+
+def _flash(q: jax.Array, k: jax.Array, v: jax.Array, window: int
+           ) -> jax.Array:
+    """The kernel on each device's share of the batch: a Mosaic kernel
+    is not partitioned by the compiler, so a data-parallel mesh runs it
+    under ``shard_map``."""
+    from repro.kernels import ops
+
+    def fn(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, window=window)
+
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or am.size == 1:
+        return fn(q, k, v)
+    spec = P(tuple(a for a in am.axis_names if a != "model"))
+    return jax.shard_map(fn, mesh=am, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
+# ---------------------------------------------------------------------------
 # Full attention sub-block (projections + rope + attention + out-proj)
 # ---------------------------------------------------------------------------
 
@@ -234,7 +275,6 @@ def attn_block(
     qk_norm: bool = False,
     norm_eps: float = 1e-6,
     compute_dtype: Any = jnp.bfloat16,
-    use_kernels: bool = False,
     cache: dict[str, jax.Array] | None = None,
 ) -> tuple[jax.Array, dict[str, jax.Array] | None]:
     """Complete attention sub-layer.  With ``cache`` (decode), x is
@@ -252,6 +292,7 @@ def attn_block(
         k = apply_rope(k, positions, rope_theta)
 
     new_cache = None
+    flash = False
     if cache is not None:
         # decode: write k,v at pos (ring for SWA), then attend over cache
         t = cache["k"].shape[1]
@@ -269,14 +310,16 @@ def attn_block(
     elif kind == "enc":
         out = full_attention(q, k, v, causal=False, softcap=softcap,
                              q_chunk=q_chunk)
+    elif flash_selected(
+            backend=jax.default_backend(), cached=cache is not None,
+            softcap=softcap, seq=s,
+            model_axis=jax.sharding.get_abstract_mesh().shape.get("model", 1)):
+        flash = True
+        out = _flash(q, k, v, window if kind == "swa" else 0)
     else:
-        if use_kernels:
-            from repro.kernels import ops as kops
-            out = kops.flash_attention(
-                q, k, v, causal=True,
-                window=window if kind == "swa" else 0)
-        else:
-            out = full_attention(q, k, v, causal=True, softcap=softcap,
-                                 q_chunk=q_chunk)
+        out = full_attention(q, k, v, causal=True, softcap=softcap,
+                             q_chunk=q_chunk)
+    from repro.core.telemetry import tally
+    tally("attn.kernel" if flash else "attn.xla")
     out = out.reshape(b, s, n_heads * head_dim)
     return out @ cast(p["wo"], compute_dtype), new_cache

@@ -61,7 +61,8 @@ class ArchConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     remat: str = "full"              # none | full | dots
-    use_kernels: bool = False        # Pallas path (TPU); XLA reference otherwise
+    use_kernels: bool = False        # Pallas SSD scan (TPU); XLA otherwise
+                                     # (attention picks its kernel itself)
     seq_shard: bool = False          # sequence-parallel activations between blocks
     loss_chunk: int = 0              # sequence-chunked CE (0 = full logits)
     vocab_pad: int = 0               # pad embed/logit tables to a multiple

@@ -187,7 +187,7 @@ def _attn_sublayer(cfg: ArchConfig, kind: str, x: jax.Array,
         q_chunk=cfg.attn_q_chunk,
         softcap=cfg.logit_softcap, qk_norm=cfg.qk_norm,
         norm_eps=cfg.norm_eps, compute_dtype=cfg.compute_dtype,
-        use_kernels=cfg.use_kernels, cache=cache)
+        cache=cache)
 
 
 def _ffn_sublayer(cfg: ArchConfig, kind: str, x: jax.Array,

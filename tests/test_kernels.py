@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps vs the ref.py oracles (interpret mode)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,8 +34,7 @@ class TestFlashAttention:
         q = rand(ks[0], (2, s, hq, d), dtype)
         k = rand(ks[1], (2, s, hkv, d), dtype)
         v = rand(ks[2], (2, s, hkv, d), dtype)
-        out = fa_kernel(q, k, v, causal=True, block_q=64, block_k=64,
-                        interpret=True)
+        out = fa_kernel(q, k, v, causal=True, block=64, interpret=True)
         want = ref.flash_attention_ref(q, k, v, causal=True)
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(want, np.float32),
@@ -45,8 +46,8 @@ class TestFlashAttention:
         q = rand(ks[0], (1, 256, 4, 32), jnp.float32)
         k = rand(ks[1], (1, 256, 2, 32), jnp.float32)
         v = rand(ks[2], (1, 256, 2, 32), jnp.float32)
-        out = fa_kernel(q, k, v, causal=True, window=window,
-                        block_q=64, block_k=64, interpret=True)
+        out = fa_kernel(q, k, v, causal=True, window=window, block=64,
+                        interpret=True)
         want = ref.flash_attention_ref(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -56,8 +57,7 @@ class TestFlashAttention:
         q = rand(ks[0], (2, 128, 4, 32), jnp.float32)
         k = rand(ks[1], (2, 128, 4, 32), jnp.float32)
         v = rand(ks[2], (2, 128, 4, 32), jnp.float32)
-        out = fa_kernel(q, k, v, causal=False, block_q=64, block_k=64,
-                        interpret=True)
+        out = fa_kernel(q, k, v, causal=False, block=64, interpret=True)
         want = ref.flash_attention_ref(q, k, v, causal=False)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -68,11 +68,117 @@ class TestFlashAttention:
         k = rand(ks[1], (1, 100, 2, 32), jnp.float32)
         v = rand(ks[2], (1, 100, 2, 32), jnp.float32)
         for causal in (True, False):
-            out = ops.flash_attention(q, k, v, causal=causal,
-                                      block_q=32, block_k=32)
+            out = ops.flash_attention(q, k, v, causal=causal)
             want = ref.flash_attention_ref(q, k, v, causal=causal)
             np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                        atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("s,hq,hkv,d,window,block,remat", [
+        (256, 4, 1, 80, 0, 128, False),       # GQA 4:1, head 80, causal
+        (256, 2, 2, 64, 0, 128, False),       # MHA 1:1, head 64
+        (256, 4, 1, 80, 4096, 128, False),    # window >= S: causal only
+        (384, 4, 1, 64, 160, 128, False),     # window < S: tiles skipped
+        (200, 4, 1, 80, 0, None, False),      # S padded to the block
+        (256, 4, 1, 80, 0, 128, True),        # under jax.checkpoint
+    ], ids=["gqa4-d80", "mha-d64", "window-ge-s", "window-lt-s", "padded",
+            "checkpoint"])
+    def test_grad_parity(self, s, hq, hkv, d, window, block, remat):
+        """The kernel's output and its own backward's (dq, dk, dv) match
+        ``jax.grad`` through the XLA attention (``full_attention``; the
+        dense oracle where the window is shorter than S)."""
+        from repro.models.attention import full_attention
+        ks = jax.random.split(KEY, 4)
+        q = rand(ks[0], (1, s, hq, d), jnp.float32)
+        k = rand(ks[1], (1, s, hkv, d), jnp.float32)
+        v = rand(ks[2], (1, s, hkv, d), jnp.float32)
+        ct = rand(ks[3], (1, s, hq, d), jnp.float32)
+        if block is None:            # the ops wrapper pads S to its block
+            kernel = functools.partial(ops.flash_attention, causal=True,
+                                       window=window)
+        else:
+            kernel = functools.partial(fa_kernel, causal=True, window=window,
+                                       block=block, interpret=True)
+        if remat:
+            kernel = jax.checkpoint(kernel)
+        if window and window < s:
+            xla = functools.partial(ref.flash_attention_ref, causal=True,
+                                    window=window)
+        else:
+            xla = functools.partial(full_attention, causal=True)
+
+        def out_and_grads(fn):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out, *vjp(ct))
+
+        got = jax.jit(lambda: out_and_grads(kernel))()
+        want = jax.jit(lambda: out_and_grads(xla))()
+        for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+class TestFlashSelection:
+    TPU = dict(backend="tpu", cached=False, softcap=0.0, seq=2048,
+               model_axis=1)
+
+    @pytest.mark.parametrize("change,want", [
+        ({}, True),
+        ({"seq": 128}, True),                # one block
+        ({"backend": "cpu"}, False),
+        ({"backend": "gpu"}, False),
+        ({"cached": True}, False),           # decode
+        ({"softcap": 50.0}, False),          # the kernel has no softcap
+        ({"seq": 127}, False),               # shorter than a block
+        ({"model_axis": 4}, False),          # query heads split
+    ])
+    def test_selected_only_where_it_applies(self, change, want):
+        from repro.models.attention import flash_selected
+        assert flash_selected(**{**self.TPU, **change}) is want
+
+    @staticmethod
+    def _danube_block(s, window=4096):
+        """h2o-danube-1.8b's attention sub-layer at its widths, with the
+        inputs, on a batch of one."""
+        from repro.models.attention import attn_block
+        ks = jax.random.split(KEY, 5)
+        d, hq, hkv, hd = 2560, 32, 8, 80
+        p = {"wq": rand(ks[0], (d, hq * hd), jnp.float32, 0.02),
+             "wk": rand(ks[1], (d, hkv * hd), jnp.float32, 0.02),
+             "wv": rand(ks[2], (d, hkv * hd), jnp.float32, 0.02),
+             "wo": rand(ks[3], (hq * hd, d), jnp.float32, 0.02)}
+        x = rand(ks[4], (1, s, d), jnp.float32)
+        return jax.jit(lambda x, p: attn_block(
+            x, p, n_heads=hq, n_kv_heads=hkv, head_dim=hd, kind="swa",
+            window=window, positions=jnp.arange(s)[None],
+            rope_theta=10_000.0)[0]), x, p
+
+    def test_cpu_danube_block_takes_xla_path(self):
+        from repro.core.telemetry import tallies
+        fn, x, p = self._danube_block(256)
+        before = tallies()
+        jaxpr = str(jax.make_jaxpr(fn)(x, p))
+        after = tallies()
+        assert "pallas_call" not in jaxpr
+        assert (after.get("papas.attn.xla", 0)
+                - before.get("papas.attn.xla", 0)) == 1
+        assert (after.get("papas.attn.kernel", 0)
+                == before.get("papas.attn.kernel", 0))
+
+    def test_tpu_selection_runs_kernel_in_block(self, monkeypatch):
+        """Where the backend reads as a TPU, the danube-shaped sub-layer
+        runs the kernel (interpreted here) and agrees with its XLA path."""
+        from repro.core.telemetry import tallies
+        fn, x, p = self._danube_block(256)
+        want = fn(x, p)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(ops, "_interpret_default", lambda: True)
+        fn, _, _ = self._danube_block(256)
+        before = tallies().get("papas.attn.kernel", 0)
+        got = fn(x, p)
+        assert tallies().get("papas.attn.kernel", 0) == before + 1
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=2e-2, rtol=2e-2)
 
 
 class TestSSDScan:

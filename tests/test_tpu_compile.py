@@ -18,24 +18,42 @@ from repro.kernels.ssd_scan import ssd_scan
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
-#: kernel -> (call, argument shapes) at a model's published widths
+
+def _flash_grads(q, k, v):
+    """(dq, dk, dv) through the kernel's own backward."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, window=4096,
+                              interpret=False)
+        return jnp.sum(out.astype(F32))
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+#: h2o-danube-1.8b's train cell: batch 4 x 2048, 32 query / 8 kv heads x 80
+DANUBE_TRAIN = [((4, 2048, 32, 80), BF16), ((4, 2048, 8, 80), BF16),
+                ((4, 2048, 8, 80), BF16)]
+
+#: kernel -> (call, argument shapes, kernel names the compiled program
+#: holds) at a model's published widths
 CASES = {
     # h2o-danube-1.8b: 32 query / 8 kv heads x 80, window 4096
     "flash_attention": (
         lambda q, k, v: flash_attention(q, k, v, causal=True, window=4096,
                                         interpret=False),
         [((1, 4096, 32, 80), BF16), ((1, 4096, 8, 80), BF16),
-         ((1, 4096, 8, 80), BF16)]),
+         ((1, 4096, 8, 80), BF16)], ("flash_fwd",)),
+    # its backward at the train cell's widths: forward, dq and dk/dv
+    "flash_attention_grad": (
+        _flash_grads, DANUBE_TRAIN, ("flash_fwd", "flash_dq", "flash_dkv")),
     # mamba2-780m: 48 heads x 64, state 128, chunk 256
     "ssd_scan": (
         lambda x, a, b, c: ssd_scan(x, a, b, c, chunk=256, interpret=False),
         [((1, 4096, 48, 64), BF16), ((1, 4096, 48), F32),
-         ((1, 4096, 1, 128), BF16), ((1, 4096, 1, 128), BF16)]),
+         ((1, 4096, 1, 128), BF16), ((1, 4096, 1, 128), BF16)], ()),
     # olmoe-1b-7b: 4096 tokens x top-8, d 2048, 64 experts x 1024
     "grouped_matmul": (
         lambda x, w, g: grouped_matmul(x, w, g, interpret=False),
         [((4096 * 8, 2048), BF16), ((64, 2048, 1024), BF16),
-         ((64,), I32)]),
+         ((64,), I32)], ()),
 }
 
 
@@ -71,7 +89,35 @@ def no_persistent_cache():
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
-    fn, shapes = CASES[name]
+    fn, shapes, kernels = CASES[name]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    for kernel in kernels:
+        assert kernel in text, kernel
+
+
+def test_flash_compiles_for_data_parallel_v5e(topo, no_persistent_cache,
+                                              monkeypatch):
+    """On a (data=4, model=1) mesh the attention sub-layer's kernel runs
+    under ``shard_map``, forward and backward: the compiler does not
+    partition a Mosaic kernel itself."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.kernels import ops
+    from repro.models.attention import _flash
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    batch = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=batch)
+            for s, d in DANUBE_TRAIN]
+
+    def loss(q, k, v):
+        return jnp.sum(_flash(q, k, v, 4096).astype(F32))
+
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile().as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert kernel in text, kernel
