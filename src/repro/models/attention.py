@@ -37,10 +37,12 @@ def _softcap(scores: jax.Array, cap: float) -> jax.Array:
 def _repeat_kv(k: jax.Array, n_q: int) -> jax.Array:
     """GQA → MHA expansion: (B,S,Hkv,D) → (B,S,Hq,D).
 
-    The repeated-KV formulation keeps every attention einsum shardable
-    over the *query*-head axis (Hq is a multiple of the TP degree even
-    when Hkv is not, e.g. kv=8 on a 16-way model axis); the expansion is
-    a cheap gather that GSPMD shards on the head dim."""
+    The repeated-KV formulation keeps every XLA attention einsum
+    shardable over the *query*-head axis (Hq is a multiple of the TP
+    degree even when Hkv is not, e.g. kv=8 on a 16-way model axis); the
+    expansion is a cheap gather that GSPMD shards on the head dim. The
+    flash kernel needs no expansion: it takes the model axis only where
+    that axis divides Hkv too (``flash_selected``)."""
     hkv = k.shape[2]
     if hkv == n_q:
         return k
@@ -225,23 +227,26 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 def flash_selected(*, backend: str, cached: bool, softcap: float, seq: int,
-                   model_axis: int) -> bool:
+                   n_heads: int, n_kv_heads: int, model_axis: int) -> bool:
     """Causal full-sequence attention runs the flash kernel on a TPU,
     without a KV cache (training, forward), without a logit softcap
-    (the kernel has none), over at least one kernel block, with the
-    query heads whole on each device (``model`` mesh axis 1)."""
+    (the kernel has none), over at least one kernel block, with whole
+    GQA groups on each device: the ``model`` mesh axis divides both the
+    query and the key/value heads (32/8 heads over 4 leave 8/2)."""
     if backend != "tpu":          # Pallas (and its import) only on a TPU
         return False
     from repro.kernels.flash_attention import LANES
     return (not cached and not softcap and seq >= LANES
-            and model_axis == 1)
+            and n_heads % model_axis == 0 and n_kv_heads % model_axis == 0)
 
 
 def _flash(q: jax.Array, k: jax.Array, v: jax.Array, window: int
            ) -> jax.Array:
-    """The kernel on each device's share of the batch: a Mosaic kernel
-    is not partitioned by the compiler, so a data-parallel mesh runs it
-    under ``shard_map``."""
+    """The kernel on each device's share: a Mosaic kernel is not
+    partitioned by the compiler, so on a mesh it runs under
+    ``shard_map``, the batch over the data axes and, where the
+    ``model`` axis is larger than one, the heads over it. Each device
+    then holds whole GQA groups, so neither pass needs a collective."""
     from repro.kernels import ops
 
     def fn(q, k, v):
@@ -250,7 +255,9 @@ def _flash(q: jax.Array, k: jax.Array, v: jax.Array, window: int
     am = jax.sharding.get_abstract_mesh()
     if am.empty or am.size == 1:
         return fn(q, k, v)
-    spec = P(tuple(a for a in am.axis_names if a != "model"))
+    batch = tuple(a for a in am.axis_names if a != "model")
+    heads = "model" if am.shape.get("model", 1) > 1 else None
+    spec = P(batch, None, heads, None)
     return jax.shard_map(fn, mesh=am, in_specs=(spec,) * 3,
                          out_specs=spec, check_vma=False)(q, k, v)
 
@@ -312,7 +319,7 @@ def attn_block(
                              q_chunk=q_chunk)
     elif flash_selected(
             backend=jax.default_backend(), cached=cache is not None,
-            softcap=softcap, seq=s,
+            softcap=softcap, seq=s, n_heads=n_heads, n_kv_heads=n_kv_heads,
             model_axis=jax.sharding.get_abstract_mesh().shape.get("model", 1)):
         flash = True
         out = _flash(q, k, v, window if kind == "swa" else 0)

@@ -118,8 +118,9 @@ class TestFlashAttention:
 
 
 class TestFlashSelection:
+    #: h2o-danube-1.8b's train step on one chip: 32 query / 8 kv heads
     TPU = dict(backend="tpu", cached=False, softcap=0.0, seq=2048,
-               model_axis=1)
+               n_heads=32, n_kv_heads=8, model_axis=1)
 
     @pytest.mark.parametrize("change,want", [
         ({}, True),
@@ -129,7 +130,11 @@ class TestFlashSelection:
         ({"cached": True}, False),           # decode
         ({"softcap": 50.0}, False),          # the kernel has no softcap
         ({"seq": 127}, False),               # shorter than a block
-        ({"model_axis": 4}, False),          # query heads split
+        ({"model_axis": 16}, False),         # kv heads split: 8 over 16
+        ({"model_axis": 4}, True),           # whole GQA groups: 8/2 a chip
+        ({"model_axis": 2}, True),
+        ({"model_axis": 3}, False),          # 32 query heads over 3
+        ({"n_heads": 12, "n_kv_heads": 4, "model_axis": 8}, False),
     ])
     def test_selected_only_where_it_applies(self, change, want):
         from repro.models.attention import flash_selected

@@ -121,3 +121,30 @@ def test_flash_compiles_for_data_parallel_v5e(topo, no_persistent_cache,
             *args).compile().as_text()
     for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert kernel in text, kernel
+
+
+def test_flash_compiles_for_head_sharded_v5e(topo, no_persistent_cache,
+                                             monkeypatch):
+    """On a (data=1, model=4) mesh, danube's 32/8 heads lie 8/2 on each
+    chip: the kernel runs under ``shard_map`` over the heads, forward
+    and backward, and neither pass gathers the heads."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.kernels import ops
+    from repro.models.attention import _flash
+    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    heads = NamedSharding(mesh, P("data", None, "model", None))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=heads)
+            for s, d in DANUBE_TRAIN]
+
+    def loss(q, k, v):
+        return jnp.sum(_flash(q, k, v, 4096).astype(F32))
+
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile().as_text()
+    for kernel in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert kernel in text, kernel
+    assert "all-gather" not in text
