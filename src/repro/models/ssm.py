@@ -134,21 +134,23 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
                  ) -> tuple[jax.Array, jax.Array | None]:
     """Depthwise causal conv1d, kernel size K.  x (B,S,C); w (K,C).
 
-    With ``state`` (B,K-1,C) performs a streaming step (S==1)."""
+    y_t = b + Σ_k w_k · x_{t-K+1+k}, summed as K shifted slices of the
+    left-padded input (no (B,S,K,C) window tensor, so the gradient is
+    slices and pads, not a scatter-add).  With ``state`` (B,K-1,C) the
+    pad is the previous K-1 inputs (a streaming step) and the new state
+    is the last K-1 rows."""
     k = w.shape[0]
-    if state is not None:
-        window = jnp.concatenate([state, x], axis=1)         # (B,K,C)
-        y = jnp.einsum("bkc,kc->bc", window.astype(jnp.float32),
-                       w.astype(jnp.float32))[:, None, :]
-        new_state = window[:, 1:]
-        return (y + b.astype(jnp.float32)).astype(x.dtype), new_state
-    pad = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    # unfold: y_t = Σ_k w_k · x_{t-K+1+k}
-    idx = jnp.arange(x.shape[1])[:, None] + jnp.arange(k)[None, :]  # (S,K)
-    windows = pad[:, idx]                                    # (B,S,K,C)
-    y = jnp.einsum("bskc,kc->bsc", windows.astype(jnp.float32),
-                   w.astype(jnp.float32)) + b.astype(jnp.float32)
-    return y.astype(x.dtype), None
+    s = x.shape[1]
+    if state is None:
+        padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))   # (B,S+K-1,C)
+    else:
+        padded = jnp.concatenate([state, x], axis=1)
+    wf = w.astype(jnp.float32)
+    y = padded[:, :s].astype(jnp.float32) * wf[0]
+    for i in range(1, k):
+        y = y + padded[:, i:i + s].astype(jnp.float32) * wf[i]
+    y = (y + b.astype(jnp.float32)).astype(x.dtype)
+    return y, None if state is None else padded[:, s:]
 
 
 def mamba2_block(
