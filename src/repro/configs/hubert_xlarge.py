@@ -1,7 +1,7 @@
 """HuBERT-XLarge [arXiv:2106.07447] — encoder-only (bidirectional),
 LayerNorm + non-gated GELU FFN, 504-class target vocabulary.  The audio
-frontend (conv feature extractor) is a stub: input_specs provides
-precomputed frame embeddings."""
+frontend (conv feature extractor) is a stub: the model takes
+precomputed frame embeddings (``input_mode="embeds"``)."""
 from repro.models.config import ArchConfig
 
 CONFIG = ArchConfig(

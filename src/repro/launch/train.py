@@ -28,7 +28,7 @@ from repro.launch.mesh import enable_compile_cache, make_local_mesh
 from repro.models.config import ArchConfig
 from repro.optim.adamw import AdamW, cosine_schedule
 from repro.train.step import (
-    TrainStepConfig, abstract_train_state, init_train_state, make_train_step,
+    abstract_train_state, init_train_state, make_train_step,
 )
 
 
@@ -43,7 +43,7 @@ def train(cfg: ArchConfig, mesh: jax.sharding.Mesh, *, steps: int,
     loss of every step run.
     """
     opt = AdamW(schedule=cosine_schedule(lr, warmup, steps))
-    step_fn = make_train_step(cfg, opt, TrainStepConfig(n_micro=n_micro))
+    step_fn = make_train_step(cfg, opt, n_micro=n_micro)
     state_sh = shd.state_shardings(abstract_train_state(cfg, opt), mesh)
     # initialize under the target shardings: each device materializes
     # only its shard, so a state that fits only when sharded still fits

@@ -1,10 +1,9 @@
 """Model substrate: configs, layers, and the 10 assigned architectures."""
-from .config import ArchConfig, ShapeConfig, SHAPES, cell_applicable
-from .model import Model, cache_specs, input_specs, synthetic_batch
+from .config import ArchConfig
+from .model import Model, synthetic_batch
 from .transformer import decode_step, forward, init_cache, init_params, loss_fn
 
 __all__ = [
-    "ArchConfig", "ShapeConfig", "SHAPES", "cell_applicable",
-    "Model", "cache_specs", "input_specs", "synthetic_batch",
+    "ArchConfig", "Model", "synthetic_batch",
     "decode_step", "forward", "init_cache", "init_params", "loss_fn",
 ]

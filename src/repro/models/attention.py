@@ -71,54 +71,28 @@ def full_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = True,
     softcap: float = 0.0,
-    q_chunk: int = 0,
 ) -> jax.Array:
-    """Masked softmax attention.
-
-    ``q_chunk`` > 0 streams query blocks through ``lax.map`` so the
-    (Sq, Sk) score buffer never exceeds (q_chunk, Sk) — the XLA
-    stand-in for the Pallas flash kernel's VMEM blocking."""
-    b, sq, hq, d = q.shape
+    """Masked softmax attention over the whole (Sq, Sk) score matrix."""
+    sq, hq = q.shape[1], q.shape[2]
     kf = _repeat_kv(k, hq)
     vf = _repeat_kv(v, hq)
     sk = kf.shape[1]
-
-    if not q_chunk or sq <= q_chunk:
-        mask = None
-        if causal:
-            mask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), k=sk - sq)
-        return _sdp(q, kf, vf, mask, softcap)
-
-    nq = sq // q_chunk
-    assert sq % q_chunk == 0, (sq, q_chunk)
-    qb = q.reshape(b, nq, q_chunk, hq, d).transpose(1, 0, 2, 3, 4)
-
-    @jax.checkpoint  # map-bwd must not stack per-chunk score residuals
-    def blk(args):
-        qi, idx = args
-        mask = None
-        if causal:
-            qpos = idx * q_chunk + jnp.arange(q_chunk)[:, None]
-            kpos = jnp.arange(sk)[None, :]
-            mask = qpos >= kpos
-        return _sdp(qi, kf, vf, mask, softcap)
-
-    out = jax.lax.map(blk, (qb, jnp.arange(nq)))
-    return out.transpose(1, 0, 2, 3, 4).reshape(b, sq, hq, d)
+    mask = None
+    if causal:
+        mask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), k=sk - sq)
+    return _sdp(q, kf, vf, mask, softcap)
 
 
 def local_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     window: int,
     causal: bool = True,
-    q_chunk: int = 0,
 ) -> jax.Array:
     """Chunk-banded sliding-window attention.
 
     Queries in chunk c attend to keys in chunks c-1 and c, masked to the
     true window: allowed iff 0 <= q_pos - k_pos < window.  Exact for
-    window <= chunk width (we use chunk = window).  ``q_chunk`` streams
-    the chunk axis through ``lax.map`` to bound the live score buffer.
+    window <= chunk width (we use chunk = window).
     """
     b, s, hq, d = q.shape
     w = min(window, s)
@@ -145,26 +119,13 @@ def local_attention(
     dist = i + w - j
     band = (dist >= 0) & (dist < w) if causal else (jnp.abs(dist) < w)
 
-    @jax.checkpoint  # see full_attention: keep map-bwd residual-free
-    def one_chunk(args):
-        qi, ki, vi, idx = args                     # (B,W,H,D)/(B,2W,H,D)
-        mask = band & ~((idx == 0) & (j < w))      # (W, 2W)
-        return _sdp(qi, ki, vi, mask[None, None], 0.0)
-
-    if q_chunk:
-        out = jax.lax.map(
-            one_chunk,
-            (qc.transpose(1, 0, 2, 3, 4), k2.transpose(1, 0, 2, 3, 4),
-             v2.transpose(1, 0, 2, 3, 4), jnp.arange(c)))
-        out = out.transpose(1, 0, 2, 3, 4)
-    else:
-        scores = jnp.einsum("bcqhd,bckhd->bchqk", qc * (d ** -0.5), k2
-                            ).astype(jnp.float32)
-        chunk_idx = jnp.arange(c)[:, None, None]
-        mask = band[None] & ~((chunk_idx == 0) & (j[None] < w))  # (C,W,2W)
-        scores = jnp.where(mask[None, :, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bchqk,bckhd->bcqhd", probs, v2)
+    scores = jnp.einsum("bcqhd,bckhd->bchqk", qc * (d ** -0.5), k2
+                        ).astype(jnp.float32)
+    chunk_idx = jnp.arange(c)[:, None, None]
+    mask = band[None] & ~((chunk_idx == 0) & (j[None] < w))  # (C,W,2W)
+    scores = jnp.where(mask[None, :, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bchqk,bckhd->bcqhd", probs, v2)
     out = out.reshape(b, sp, hq, d)
     return out[:, :s] if pad else out
 
@@ -277,7 +238,6 @@ def attn_block(
     window: int,
     positions: jax.Array,
     rope_theta: float,
-    q_chunk: int = 0,
     softcap: float = 0.0,
     qk_norm: bool = False,
     norm_eps: float = 1e-6,
@@ -294,9 +254,9 @@ def attn_block(
     if qk_norm:
         q = rms_norm(q, p["q_norm"], norm_eps)
         k = rms_norm(k, p["k_norm"], norm_eps)
-    if kind != "enc" or True:  # encoders also use rope here (positional)
-        q = apply_rope(q, positions, rope_theta)
-        k = apply_rope(k, positions, rope_theta)
+    # every kind takes RoPE, encoders too (their positional signal here)
+    q = apply_rope(q, positions, rope_theta)
+    k = apply_rope(k, positions, rope_theta)
 
     new_cache = None
     flash = False
@@ -313,10 +273,9 @@ def attn_block(
         out = decode_attention(q, k_cache, v_cache, lengths, softcap)
         new_cache = {"k": k_cache, "v": v_cache, "pos": pos + 1}
     elif kind == "swa" and window and s > window:
-        out = local_attention(q, k, v, window, causal=True, q_chunk=q_chunk)
+        out = local_attention(q, k, v, window, causal=True)
     elif kind == "enc":
-        out = full_attention(q, k, v, causal=False, softcap=softcap,
-                             q_chunk=q_chunk)
+        out = full_attention(q, k, v, causal=False, softcap=softcap)
     elif flash_selected(
             backend=jax.default_backend(), cached=cache is not None,
             softcap=softcap, seq=s, n_heads=n_heads, n_kv_heads=n_kv_heads,
@@ -324,8 +283,7 @@ def attn_block(
         flash = True
         out = _flash(q, k, v, window if kind == "swa" else 0)
     else:
-        out = full_attention(q, k, v, causal=True, softcap=softcap,
-                             q_chunk=q_chunk)
+        out = full_attention(q, k, v, causal=True, softcap=softcap)
     from repro.core.telemetry import tally
     tally("attn.kernel" if flash else "attn.xla")
     out = out.reshape(b, s, n_heads * head_dim)

@@ -6,7 +6,6 @@ One dataclass describes every assigned architecture; per-arch modules in
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,15 +59,8 @@ class ArchConfig:
     # -- runtime --
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
-    remat: str = "full"              # none | full | dots
     use_kernels: bool = False        # Pallas SSD scan (TPU); XLA otherwise
                                      # (attention picks its kernel itself)
-    seq_shard: bool = False          # sequence-parallel activations between blocks
-    loss_chunk: int = 0              # sequence-chunked CE (0 = full logits)
-    vocab_pad: int = 0               # pad embed/logit tables to a multiple
-                                     # (runtime shardability; pad logits masked)
-    attn_q_chunk: int = 0            # stream attention query blocks via
-                                     # lax.map (XLA stand-in for flash)
 
     def __post_init__(self) -> None:
         if self.layer_types and len(self.layer_types) != self.n_layers:
@@ -77,13 +69,6 @@ class ArchConfig:
                 f"for {self.n_layers} layers")
 
     # -- derived ----------------------------------------------------------
-    @property
-    def padded_vocab(self) -> int:
-        if not self.vocab_pad:
-            return self.vocab_size
-        p = self.vocab_pad
-        return ((self.vocab_size + p - 1) // p) * p
-
     @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
@@ -109,13 +94,6 @@ class ArchConfig:
     def has_decode(self) -> bool:
         """Encoder-only architectures have no autoregressive step."""
         return self.causal
-
-    def subquadratic(self) -> bool:
-        """True when the arch has at least one sub-quadratic sequence
-        mechanism (SSM state or sliding window) — gates the long_500k
-        cell.  Pure full-attention archs are skipped per the assignment."""
-        kinds = set(self.layer_types)
-        return bool(kinds & {"swa", "ssm", "hyb_l"})
 
     def param_count(self) -> int:
         """Exact parameter count from the config (embedding included)."""
@@ -174,34 +152,3 @@ class ArchConfig:
         n_moe_layers = sum(1 for k in self.layer_types if k == "moe")
         inactive += n_moe_layers * (self.n_experts - self.top_k) * 3 * d * self.moe_d_ff
         return self.param_count() - inactive
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    """One input-shape cell from the assignment."""
-
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                 # train | prefill | decode
-
-    @property
-    def tokens(self) -> int:
-        return self.seq_len * self.global_batch
-
-
-SHAPES: dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-
-def cell_applicable(cfg: ArchConfig, shape: ShapeConfig) -> tuple[bool, str]:
-    """Whether an (arch × shape) cell runs; returns (ok, reason-if-not)."""
-    if shape.kind == "decode" and not cfg.has_decode():
-        return False, "encoder-only: no autoregressive decode step"
-    if shape.name == "long_500k" and not cfg.subquadratic():
-        return False, "pure full-attention arch: long_500k needs sub-quadratic attention"
-    return True, ""

@@ -1,9 +1,7 @@
 """Public model API: a thin functional wrapper over the substrate.
 
-``Model`` binds an ArchConfig to init/loss/decode callables, and
-``input_specs`` produces ShapeDtypeStruct stand-ins for every input of a
-given (arch × shape) cell — the dry-run lowers against these without
-allocating anything.
+``Model`` binds an ArchConfig to init/loss/decode callables;
+``synthetic_batch`` makes a random batch of its inputs.
 """
 from __future__ import annotations
 
@@ -13,7 +11,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .config import ArchConfig, ShapeConfig
+from .config import ArchConfig
 from .transformer import (
     decode_step, forward, init_cache, init_params, loss_fn,
 )
@@ -27,52 +25,21 @@ class Model:
         return init_params(self.cfg, key)
 
     def init_abstract(self, key: jax.Array | None = None):
-        """Parameter shapes without allocation (for dry-run sharding)."""
+        """Parameter shapes without allocation (for sharding rules)."""
         key = key if key is not None else jax.random.PRNGKey(0)
         return jax.eval_shape(lambda k: init_params(self.cfg, k), key)
 
-    def forward(self, params, batch, moe_groups: int = 1):
-        return forward(self.cfg, params, batch, moe_groups)
+    def forward(self, params, batch):
+        return forward(self.cfg, params, batch)
 
-    def loss(self, params, batch, moe_groups: int = 1):
-        return loss_fn(self.cfg, params, batch, moe_groups)
+    def loss(self, params, batch):
+        return loss_fn(self.cfg, params, batch)
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
         return init_cache(self.cfg, batch, max_len, dtype)
 
     def decode_step(self, params, cache, token):
         return decode_step(self.cfg, params, cache, token)
-
-
-def input_specs(cfg: ArchConfig, shape: ShapeConfig) -> dict[str, Any]:
-    """ShapeDtypeStruct stand-ins for one (arch × shape) cell.
-
-    train/prefill → the training batch; decode → one-token step inputs
-    (the KV cache spec is produced separately via ``cache_specs``).
-    """
-    b, s = shape.global_batch, shape.seq_len
-    f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
-    sds = jax.ShapeDtypeStruct
-    if shape.kind in ("train", "prefill"):
-        if cfg.input_mode == "tokens":
-            return {"tokens": sds((b, s), i32), "labels": sds((b, s), i32)}
-        if cfg.input_mode == "embeds":
-            return {"embeds": sds((b, s, cfg.d_model), bf16),
-                    "labels": sds((b, s), i32)}
-        # mixed: patches occupy the first n_patches positions
-        st = s - cfg.n_patches
-        return {"tokens": sds((b, st), i32),
-                "patch_embeds": sds((b, cfg.n_patches, cfg.d_model), bf16),
-                "labels": sds((b, s), i32)}
-    # decode: one new token against a seq_len-deep cache
-    return {"token": sds((b, 1), i32)}
-
-
-def cache_specs(cfg: ArchConfig, shape: ShapeConfig,
-                dtype=jnp.bfloat16) -> Any:
-    """Abstract KV/SSM cache for a decode cell (no allocation)."""
-    return jax.eval_shape(
-        lambda: init_cache(cfg, shape.global_batch, shape.seq_len, dtype))
 
 
 def synthetic_batch(cfg: ArchConfig, batch: int, seq: int,

@@ -69,16 +69,12 @@ def moe_einsum(
     capacity_factor: float,
     act: str,
     router_renorm: bool,
-    groups: int,
     compute_dtype: Any = jnp.bfloat16,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
-    """GShard capacity dispatch.  Tokens reshaped to (G, Tg); capacity is
-    per-group.  Returns (output (T,d), aux losses)."""
+    """GShard capacity dispatch over all T tokens as one group (G = 1);
+    capacity is per group.  Returns (output (T,d), aux losses)."""
     t_total, d = x.shape
-    g = max(1, min(groups, t_total))
-    while t_total % g:
-        g -= 1
-    tg = t_total // g
+    g, tg = 1, t_total
     capacity = max(top_k, int(tg * top_k * capacity_factor / n_experts))
     capacity = ((capacity + 31) // 32) * 32   # model-axis shardable
     xg = x.reshape(g, tg, d)
@@ -303,7 +299,6 @@ def moe_block(
     act: str,
     router_renorm: bool,
     dispatch: str,
-    groups: int,
     compute_dtype: Any = jnp.bfloat16,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Full MoE FFN: routed experts (+ optional fused shared expert)."""
@@ -323,8 +318,7 @@ def moe_block(
         routed, aux = moe_einsum(
             flat, p, n_experts=n_experts, top_k=top_k,
             capacity_factor=capacity_factor, act=act,
-            router_renorm=router_renorm, groups=groups,
-            compute_dtype=compute_dtype)
+            router_renorm=router_renorm, compute_dtype=compute_dtype)
     out = routed
     if n_shared:
         fn = _act(act)

@@ -12,7 +12,6 @@ scan carry.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -138,7 +137,7 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> dict[str, Any]:
     keys = jax.random.split(key, cfg.n_layers + 4)
     dt = cfg.param_dtype
     params: dict[str, Any] = {}
-    params["embed"] = normal_init(keys[0], (cfg.padded_vocab, cfg.d_model), dt)
+    params["embed"] = normal_init(keys[0], (cfg.vocab_size, cfg.d_model), dt)
     if cfg.input_mode in ("embeds", "mixed"):
         params["frontend_proj"] = normal_init(
             keys[1], (cfg.d_model, cfg.d_model), dt)
@@ -158,7 +157,7 @@ def init_params(cfg: ArchConfig, key: jax.Array) -> dict[str, Any]:
         params["final_norm"] = jnp.zeros((cfg.d_model,), dt)
     if not cfg.tie_embeddings:
         params["lm_head"] = normal_init(
-            keys[2 + cfg.n_layers], (cfg.d_model, cfg.padded_vocab), dt)
+            keys[2 + cfg.n_layers], (cfg.d_model, cfg.vocab_size), dt)
     return params
 
 
@@ -184,14 +183,13 @@ def _attn_sublayer(cfg: ArchConfig, kind: str, x: jax.Array,
         n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, kind=attn_kind, window=cfg.window,
         positions=positions, rope_theta=theta,
-        q_chunk=cfg.attn_q_chunk,
         softcap=cfg.logit_softcap, qk_norm=cfg.qk_norm,
         norm_eps=cfg.norm_eps, compute_dtype=cfg.compute_dtype,
         cache=cache)
 
 
 def _ffn_sublayer(cfg: ArchConfig, kind: str, x: jax.Array,
-                  lp: dict[str, Any], moe_groups: int
+                  lp: dict[str, Any]
                   ) -> tuple[jax.Array, dict[str, jax.Array]]:
     if kind == "moe":
         return moe_block(
@@ -199,7 +197,7 @@ def _ffn_sublayer(cfg: ArchConfig, kind: str, x: jax.Array,
             n_experts=cfg.n_experts, n_shared=cfg.n_shared_experts,
             top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
             act=cfg.mlp_act, router_renorm=cfg.router_renorm,
-            dispatch=cfg.moe_dispatch, groups=moe_groups,
+            dispatch=cfg.moe_dispatch,
             compute_dtype=cfg.compute_dtype)
     return mlp(x, lp["mlp"], cfg.mlp_act, cfg.compute_dtype), ZERO_AUX()
 
@@ -210,7 +208,6 @@ def layer_body(
     x: jax.Array,
     lp: dict[str, Any],
     positions: jax.Array,
-    moe_groups: int,
     cache: dict[str, jax.Array] | None = None,
 ) -> tuple[jax.Array, dict[str, jax.Array], Any]:
     """One layer: returns (x, aux, new_cache).
@@ -258,7 +255,7 @@ def layer_body(
 
     with jax.named_scope("ffn"):
         h2 = _norm(x, lp["norm2"], eps)
-        f_out, aux = _ffn_sublayer(cfg, kind, h2, lp, moe_groups)
+        f_out, aux = _ffn_sublayer(cfg, kind, h2, lp)
         return x + f_out.astype(x.dtype), aux, new_cache
 
 
@@ -281,36 +278,16 @@ def _embed_inputs(cfg: ArchConfig, params: dict[str, Any],
     return jnp.concatenate([patches, tokens], axis=1)
 
 
-def _remat(cfg: ArchConfig, fn):
-    if cfg.remat == "none":
-        return fn
-    if cfg.remat == "dots":
-        policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
-        return jax.checkpoint(fn, policy=policy)
-    return jax.checkpoint(fn)
-
-
 def backbone(
     cfg: ArchConfig,
     params: dict[str, Any],
     batch: dict[str, jax.Array],
-    moe_groups: int = 1,
-    seq_spec: Any = None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Embeddings → layers → final norm.  Returns (x (B,S,d), aux).
 
-    ``seq_spec`` (a sharding for (B,S,d) activations) enables
-    sequence-parallel residual-stream sharding: the constraint is applied
-    inside each scan body so the remat-saved carry is stored sharded —
-    the memory lever that fits 26B-scale activations per chip.
-    """
-    def _constrain(t: jax.Array) -> jax.Array:
-        if seq_spec is None:
-            return t
-        return jax.lax.with_sharding_constraint(t, seq_spec)
-
+    Each segment's scan body is rematerialized in the backward pass."""
     with jax.named_scope("embed"):
-        x = _constrain(_embed_inputs(cfg, params, batch))
+        x = _embed_inputs(cfg, params, batch)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     aux_total = ZERO_AUX()
@@ -318,12 +295,12 @@ def backbone(
     for (kind, count), seg_params in zip(cfg.segments(), params["segments"]):
         def seg_body(carry, lp, _kind=kind):
             xc, aux_acc = carry
-            xn, aux, _ = layer_body(cfg, _kind, xc, lp, positions, moe_groups)
+            xn, aux, _ = layer_body(cfg, _kind, xc, lp, positions)
             aux_acc = jax.tree.map(jnp.add, aux_acc, aux)
-            return (_constrain(xn), aux_acc), None
+            return (xn, aux_acc), None
 
-        body = _remat(cfg, seg_body)
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), seg_params)
+        (x, aux_total), _ = jax.lax.scan(
+            jax.checkpoint(seg_body), (x, aux_total), seg_params)
 
     with jax.named_scope("head"):
         x = _norm(x, params["final_norm"], cfg.norm_eps)
@@ -339,24 +316,16 @@ def forward(
     cfg: ArchConfig,
     params: dict[str, Any],
     batch: dict[str, jax.Array],
-    moe_groups: int = 1,
-    seq_spec: Any = None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Full forward pass → (logits (B,S,V), aux losses)."""
-    x, aux_total = backbone(cfg, params, batch, moe_groups, seq_spec)
-    logits = unembed(x, _head(cfg, params), cfg.compute_dtype)
-    return logits[..., :cfg.vocab_size], aux_total
+    x, aux_total = backbone(cfg, params, batch)
+    return unembed(x, _head(cfg, params), cfg.compute_dtype), aux_total
 
 
 def _ce_terms(x: jax.Array, head: jax.Array, labels: jax.Array,
-              compute_dtype: Any, vocab_size: int) -> jax.Array:
-    """Summed masked NLL for one (B,C,d) slice (logits never escape).
-    Pad-vocab columns (>= vocab_size) are masked out of the softmax."""
+              compute_dtype: Any) -> jax.Array:
+    """Summed masked NLL over (B,S,d) (logits never escape)."""
     logits = unembed(x, head, compute_dtype).astype(jnp.float32)
-    if logits.shape[-1] > vocab_size:
-        col = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
-                                       logits.ndim - 1)
-        logits = jnp.where(col < vocab_size, logits, -1e30)
     mask = (labels >= 0).astype(jnp.float32)
     safe = jnp.maximum(labels, 0)
     lse = jax.nn.logsumexp(logits, axis=-1)
@@ -368,45 +337,16 @@ def loss_fn(
     cfg: ArchConfig,
     params: dict[str, Any],
     batch: dict[str, jax.Array],
-    moe_groups: int = 1,
-    seq_spec: Any = None,
 ) -> tuple[jax.Array, dict[str, jax.Array]]:
     """Masked causal-LM cross entropy (+ MoE aux).  labels < 0 ignored.
 
-    With ``cfg.loss_chunk`` the CE is computed over sequence chunks
-    (unrolled + rematerialized) so the (B,S,V) logits are never resident
-    — the standard big-vocab memory fix.  Named scopes: ``embed`` (the
-    input lookup), ``mixer`` and ``ffn`` (each layer), ``head`` (the
-    final norm, the LM head and the cross entropy)."""
-    x, aux = backbone(cfg, params, batch, moe_groups, seq_spec)
+    Named scopes: ``embed`` (the input lookup), ``mixer`` and ``ffn``
+    (each layer), ``head`` (the final norm, the LM head and the cross
+    entropy)."""
+    x, aux = backbone(cfg, params, batch)
     with jax.named_scope("head"):
         labels = batch["labels"]
-        head = _head(cfg, params)
-        if seq_spec is not None and hasattr(seq_spec, "mesh"):
-            # pin the (d, V) head so the CE-scan grad accumulator stays
-            # vocab-sharded (GSPMD loses it through the tied-embed
-            # transpose)
-            from jax.sharding import NamedSharding, PartitionSpec
-            head = jax.lax.with_sharding_constraint(
-                head,
-                NamedSharding(seq_spec.mesh, PartitionSpec(None, "model")))
-        b, s, d = x.shape
-        chunk = cfg.loss_chunk
-        if chunk and s > chunk and s % chunk == 0:
-            nc = s // chunk
-            xs = x.reshape(b, nc, chunk, d).transpose(1, 0, 2, 3)
-            ls = labels.reshape(b, nc, chunk).transpose(1, 0, 2)
-
-            def ce_body(acc, inp):
-                xc, lc = inp
-                return acc + _ce_terms(xc, head, lc, cfg.compute_dtype,
-                                       cfg.vocab_size), None
-
-            nll_sum, _ = jax.lax.scan(jax.checkpoint(ce_body),
-                                      jnp.zeros((), jnp.float32), (xs, ls))
-        else:
-            nll_sum = _ce_terms(x, head, labels, cfg.compute_dtype,
-                                cfg.vocab_size)
+        nll_sum = _ce_terms(x, _head(cfg, params), labels, cfg.compute_dtype)
         denom = jnp.maximum((labels >= 0).sum(), 1).astype(jnp.float32)
         ce = nll_sum / denom
         loss = (ce
@@ -482,7 +422,7 @@ def decode_step(
                       "ssm": {**lc["ssm"], "pos": pos}}
             else:
                 lc = {**lc, "pos": pos}
-            xn, _, nc = layer_body(cfg, _kind, xc, lp, positions, 1, cache=lc)
+            xn, _, nc = layer_body(cfg, _kind, xc, lp, positions, cache=lc)
             # strip pos scalars so the stacked ys stay uniform
             if _kind in ("attn", "swa", "moe", "enc", "ssm"):
                 nc = {k: v for k, v in nc.items() if k != "pos"}
@@ -496,5 +436,4 @@ def decode_step(
 
     x = _norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(x, _head(cfg, params), cfg.compute_dtype)[:, 0]
-    return (logits[..., :cfg.vocab_size],
-            {"pos": pos + 1, "segments": new_segments})
+    return logits, {"pos": pos + 1, "segments": new_segments}

@@ -1,10 +1,7 @@
 """AdamW + schedules + gradient utilities (pure JAX, no optax dependency).
 
-Includes the distributed-training extras the framework exposes:
-* global-norm clipping,
-* gradient accumulation (microbatching) helper,
-* int8 gradient compression/decompression for bandwidth-bound
-  data-parallel reduction (used as a §Perf option on the pod axis).
+Besides the optimizer: global-norm clipping and the gradient
+accumulation (microbatching) helper.
 """
 from __future__ import annotations
 
@@ -141,24 +138,3 @@ def accumulate_grads(loss_fn: Callable, params: Pytree, batches: Pytree,
     aux_last = jax.tree.map(lambda x: x[-1], auxs)
     return grads, loss_sum / n_micro, aux_last
 
-
-# ---------------------------------------------------------------------------
-# int8 gradient compression (pod-axis all-reduce bandwidth saver)
-# ---------------------------------------------------------------------------
-
-def compress_int8(tree: Pytree) -> Pytree:
-    """Per-leaf symmetric int8 quantization: (q, scale)."""
-    def q(x):
-        amax = jnp.max(jnp.abs(x)) + 1e-12
-        scale = amax / 127.0
-        return {"q": jnp.clip(jnp.round(x / scale), -127, 127
-                              ).astype(jnp.int8),
-                "scale": scale.astype(jnp.float32)}
-    return jax.tree.map(q, tree)
-
-
-def decompress_int8(tree: Pytree) -> Pytree:
-    is_leaf = lambda x: isinstance(x, dict) and set(x) == {"q", "scale"}  # noqa: E731
-    return jax.tree.map(
-        lambda x: x["q"].astype(jnp.float32) * x["scale"],
-        tree, is_leaf=is_leaf)

@@ -1,10 +1,10 @@
 """Serving: batched prefill + decode steps with KV/SSM caches.
 
-``make_serve_step`` returns the one-token decode function the dry-run
-lowers for the ``decode_*``/``long_*`` shape cells; ``ServeEngine`` is
-the runnable batching loop used by the serving example (continuous
-token-level batching over a fixed slot pool — the inference analogue of
-the paper's "group many small jobs into one allocation").
+``make_serve_step`` returns the one-token decode function;
+``ServeEngine`` is the runnable batching loop used by the serving
+example and ``launch/serve.py`` (continuous token-level batching over a
+fixed slot pool — the inference analogue of the paper's "group many
+small jobs into one allocation").
 """
 from __future__ import annotations
 

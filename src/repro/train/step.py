@@ -1,8 +1,6 @@
-"""Train-step factory: loss → grads → AdamW, with microbatching and
-optional int8 gradient compression on the data-parallel reduction."""
+"""Train-step factory: loss → grads → AdamW, with microbatching."""
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Callable
 
 import jax
@@ -10,19 +8,10 @@ import jax.numpy as jnp
 
 from repro.models.config import ArchConfig
 from repro.models.transformer import loss_fn
-from repro.optim.adamw import AdamW, accumulate_grads, compress_int8, decompress_int8
+from repro.optim.adamw import AdamW, accumulate_grads
 
 
-@dataclasses.dataclass(frozen=True)
-class TrainStepConfig:
-    n_micro: int = 1              # gradient-accumulation microbatches
-    moe_groups: int = 1           # GShard dispatch groups
-    compress_grads: bool = False  # int8 round-trip on the DP reduction
-    seq_spec: Any = None          # sequence-parallel activation PartitionSpec
-
-
-def make_train_step(cfg: ArchConfig, opt: AdamW,
-                    step_cfg: TrainStepConfig = TrainStepConfig()
+def make_train_step(cfg: ArchConfig, opt: AdamW, n_micro: int = 1
                     ) -> Callable:
     """Returns ``train_step(state, batch) -> (state, metrics)``.
 
@@ -32,27 +21,19 @@ def make_train_step(cfg: ArchConfig, opt: AdamW,
     """
 
     def _loss(params, batch):
-        return loss_fn(cfg, params, batch, step_cfg.moe_groups,
-                       step_cfg.seq_spec)
+        return loss_fn(cfg, params, batch)
 
     def train_step(state: dict[str, Any], batch: dict[str, jax.Array]
                    ) -> tuple[dict[str, Any], dict[str, jax.Array]]:
         params = state["params"]
-        if step_cfg.n_micro > 1:
+        if n_micro > 1:
             micro = jax.tree.map(
-                lambda x: x.reshape((step_cfg.n_micro,
-                                     x.shape[0] // step_cfg.n_micro)
+                lambda x: x.reshape((n_micro, x.shape[0] // n_micro)
                                     + x.shape[1:]), batch)
-            grads, loss, aux = accumulate_grads(
-                _loss, params, micro, step_cfg.n_micro)
+            grads, loss, aux = accumulate_grads(_loss, params, micro, n_micro)
         else:
             (loss, aux), grads = jax.value_and_grad(
                 _loss, has_aux=True)(params, batch)
-
-        if step_cfg.compress_grads:
-            # int8 quantize → (implicit psum by GSPMD) → dequantize.
-            # The quantized representation is what crosses the pod links.
-            grads = decompress_int8(compress_int8(grads))
 
         new_params, new_opt, opt_metrics = opt.update(
             grads, state["opt"], params)
@@ -73,6 +54,7 @@ def init_train_state(cfg: ArchConfig, opt: AdamW, key: jax.Array
 
 
 def abstract_train_state(cfg: ArchConfig, opt: AdamW) -> Any:
-    """ShapeDtypeStruct state for dry-run lowering (no allocation)."""
+    """The state's ShapeDtypeStructs, without allocating it: what the
+    sharding rules and ``jit(...).lower`` take before the state is made."""
     key = jax.random.PRNGKey(0)
     return jax.eval_shape(lambda k: init_train_state(cfg, opt, k), key)

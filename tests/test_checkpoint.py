@@ -88,7 +88,7 @@ class TestElastic:
         """Save at step 2, keep training to 4; restart from ckpt and
         re-train — trajectories match (determinism of resume)."""
         from repro.data.pipeline import SyntheticStream
-        from repro.train.step import TrainStepConfig, make_train_step
+        from repro.train.step import make_train_step
 
         cfg, opt, state = small_state()
         step_fn = jax.jit(make_train_step(cfg, opt))

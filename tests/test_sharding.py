@@ -131,9 +131,3 @@ class TestMesh:
         mesh = make_local_mesh(devices=jax.devices()[:1])
         assert mesh.axis_names == ("data", "model")
         assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
-
-    def test_peaks_keyed_by_device_kind(self):
-        from repro.launch.mesh import peaks
-        assert peaks("TPU v5 lite")["flops_bf16"] == 197e12
-        with pytest.raises(KeyError, match="no published peaks"):
-            peaks("cpu")

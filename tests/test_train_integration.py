@@ -7,10 +7,9 @@ import pytest
 from repro.configs import get_smoke
 from repro.data.pipeline import SyntheticStream
 from repro.optim.adamw import (
-    AdamW, compress_int8, cosine_schedule, decompress_int8, global_norm,
-    linear_schedule,
+    AdamW, cosine_schedule, global_norm, linear_schedule,
 )
-from repro.train.step import TrainStepConfig, init_train_state, make_train_step
+from repro.train.step import init_train_state, make_train_step
 
 KEY = jax.random.PRNGKey(11)
 
@@ -42,8 +41,7 @@ class TestTrainingLoop:
         stream = SyntheticStream(cfg, global_batch=4, seq_len=16, seed=0)
         batch = {k: jnp.asarray(v) for k, v in stream.batch_at(0).items()}
         s1, m1 = jax.jit(make_train_step(cfg, opt))(state1, batch)
-        s2, m2 = jax.jit(make_train_step(
-            cfg, opt, TrainStepConfig(n_micro=2)))(state2, batch)
+        s2, m2 = jax.jit(make_train_step(cfg, opt, n_micro=2))(state2, batch)
         # bf16 compute reassociates across the micro split: ~1% slack
         np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
                                    rtol=1e-2)
@@ -52,21 +50,6 @@ class TestTrainingLoop:
             np.testing.assert_allclose(
                 np.asarray(a, np.float32), np.asarray(b, np.float32),
                 atol=2e-2)
-
-    def test_compressed_grads_still_train(self):
-        cfg = get_smoke("deepseek-7b")
-        opt = AdamW(schedule=cosine_schedule(3e-3, 5, 40), weight_decay=0.0)
-        state = init_train_state(cfg, opt, KEY)
-        step = jax.jit(make_train_step(
-            cfg, opt, TrainStepConfig(compress_grads=True)))
-        toks = jax.random.randint(KEY, (4, 32), 0, cfg.vocab_size)
-        batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
-        first = last = None
-        for i in range(40):
-            state, m = step(state, batch)
-            first = first or float(m["loss"])
-            last = float(m["loss"])
-        assert last < first * 0.85
 
 
 class TestOptim:
@@ -85,13 +68,6 @@ class TestOptim:
         grads = {"w": jnp.full((8, 8), 1e6)}
         _, _, metrics = opt.update(grads, state, params)
         assert float(metrics["grad_norm"]) > 1e6
-
-    def test_int8_roundtrip_error_bounded(self):
-        tree = {"a": jax.random.normal(KEY, (64, 64))}
-        rt = decompress_int8(compress_int8(tree))
-        err = jnp.abs(rt["a"] - tree["a"]).max()
-        amax = jnp.abs(tree["a"]).max()
-        assert float(err) <= float(amax) / 127.0 + 1e-6
 
     def test_global_norm(self):
         tree = {"a": jnp.ones((3,)), "b": jnp.ones((4,))}
